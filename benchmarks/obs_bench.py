@@ -29,12 +29,6 @@ an output dir, not the CWD) and merges the ``obs_overhead`` record into
 
 from __future__ import annotations
 
-try:  # runs both as a script and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import dataclasses
 import json
 import os

@@ -5,12 +5,6 @@ whole-grid speedup over the host loop (the Table-1 acceptance number)."""
 
 from __future__ import annotations
 
-try:  # runs both as `python benchmarks/policy_overhead.py` and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import time
 
 import jax.numpy as jnp

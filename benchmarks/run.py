@@ -19,12 +19,7 @@ import sys
 # holds benchmarks/, not the repo root that anchors the benchmarks package)
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from benchmarks.xla_env import (  # noqa: E402
-    enable_fast_cpu_scan,
-    set_host_device_count,
-)
-
-enable_fast_cpu_scan()  # must run before anything imports jax
+from benchmarks.xla_env import set_host_device_count  # noqa: E402
 
 import argparse
 import inspect
@@ -66,6 +61,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     if args.devices is not None:
         set_host_device_count(args.devices)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     out_lines = []
     sections = []

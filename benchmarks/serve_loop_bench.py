@@ -20,12 +20,6 @@ Lands the ``serve_loop`` section in the ``--sweep-json`` perf artifact.
 
 from __future__ import annotations
 
-try:  # runs both as a script and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import dataclasses
 import json
 import os

@@ -17,12 +17,6 @@ decisions, so the comparison is apples-to-apples by construction.
 
 from __future__ import annotations
 
-try:  # runs both as `python benchmarks/serve_policy_bench.py` and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import time
 
 import jax

@@ -24,12 +24,6 @@ before jax loads) or set XLA_FLAGS yourself.
 
 from __future__ import annotations
 
-try:  # runs both as `python benchmarks/sharded_sweep.py` and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import json
 import os
 import time

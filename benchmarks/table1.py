@@ -8,12 +8,6 @@ policy x frame-size grid, bit-identical to the host oracles."""
 
 from __future__ import annotations
 
-try:  # runs both as `python benchmarks/table1.py` and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import numpy as np
 
 from repro.core import hit_ratio_table, sweep

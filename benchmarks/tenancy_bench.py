@@ -23,12 +23,6 @@ three mounts are apples-to-apples by construction.
 
 from __future__ import annotations
 
-try:  # runs both as `python benchmarks/tenancy_bench.py` and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import json
 import os
 import time

@@ -6,12 +6,6 @@ engine per trace; 2q/opt stay on the host oracle path."""
 
 from __future__ import annotations
 
-try:  # runs both as `python benchmarks/trace_suite.py` and as a module
-    from benchmarks.xla_env import enable_fast_cpu_scan
-except ImportError:
-    from xla_env import enable_fast_cpu_scan
-enable_fast_cpu_scan()
-
 import numpy as np
 
 from repro.core import sweep

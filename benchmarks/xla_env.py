@@ -1,41 +1,20 @@
-"""Opt-in XLA tuning for benchmark entrypoints.
-
-``--xla_cpu_use_thunk_runtime=false`` selects the legacy XLA:CPU runtime,
-which updates ``lax.scan`` carries in place; the thunk runtime copies every
-scatter operand per step, which multiplies the batched sweep engine's
-per-step cost ~4x (measured in benchmarks/policy_overhead.py).  Library code
-stays flag-agnostic — only the benchmark entrypoints opt in, and only if the
-operator hasn't already configured the knob.  Must run before jax imports.
-"""
+"""XLA host-device setup for benchmark entrypoints (must run before jax
+imports)."""
 
 from __future__ import annotations
 
 import os
 import sys
 
-_FLAG = "--xla_cpu_use_thunk_runtime=false"
-
-
-def enable_fast_cpu_scan() -> None:
-    """Select the legacy (in-place scan) XLA:CPU runtime via ``XLA_FLAGS``.
-
-    No-op if jax was already imported (the flag would be ignored) or if the
-    operator configured the knob themselves."""
-    if "jax" in sys.modules:
-        return  # too late — jax already read XLA_FLAGS
-    flags = os.environ.get("XLA_FLAGS", "")
-    if "xla_cpu_use_thunk_runtime" not in flags:
-        os.environ["XLA_FLAGS"] = f"{flags} {_FLAG}".strip()
-
 
 def set_host_device_count(n: int) -> None:
     """Expose ``n`` XLA host-platform devices for mesh benchmarks.
 
     Appends ``--xla_force_host_platform_device_count=n`` to ``XLA_FLAGS``.
-    Like :func:`enable_fast_cpu_scan` this must run before the first jax
-    import — a :class:`RuntimeError` is raised if it is already too late,
-    because silently benchmarking on the wrong device count would corrupt
-    the recorded scaling numbers.  An operator-provided count is respected.
+    This must run before the first jax import — a :class:`RuntimeError` is
+    raised if it is already too late, because silently benchmarking on the
+    wrong device count would corrupt the recorded scaling numbers.  An
+    operator-provided count is respected.
     """
     if int(n) < 1:
         raise ValueError(f"device count must be >= 1, got {n}")

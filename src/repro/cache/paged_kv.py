@@ -48,6 +48,7 @@ from repro.core.policy_core import (
     AdaptiveCore,
     AdaptiveState,
     awrp_victim_rows,
+    div_rn,
     first_min,
 )
 
@@ -195,7 +196,7 @@ def referenced_pages(
     B, P = pool.f.shape
     mass = attn_mass.reshape(B, P, page_size).sum(-1)  # (B, P)
     resident = (pool.page_start >= 0).sum(-1, keepdims=True)  # (B, 1)
-    tau = 1.0 / jnp.maximum(resident.astype(jnp.float32), 1.0)
+    tau = div_rn(1.0, jnp.maximum(resident.astype(jnp.float32), 1.0))
     return (mass >= tau) & (pool.page_start >= 0)
 
 
@@ -211,6 +212,29 @@ def score_update(
     f = jnp.where(referenced, pool.f + 1, pool.f)
     r = jnp.where(referenced, clock[:, None], pool.r)
     return pool._replace(f=f, r=r, clock=clock)
+
+
+def _pools(caches):
+    """Every ``PagedPool`` in a cache tree (the ``pool`` of an adaptive one),
+    in traversal order."""
+    is_pool = lambda x: isinstance(x, (PagedPool, AdaptivePagedPool))  # noqa: E731
+    return [x.pool if isinstance(x, AdaptivePagedPool) else x
+            for x in jax.tree.leaves(caches, is_leaf=is_pool) if is_pool(x)]
+
+
+def step_counts(old_caches, new_caches) -> Tuple[jax.Array, jax.Array]:
+    """``(hits, evictions)`` one decode step made, summed over every paged
+    pool of a cache tree (layers and sequences): a page was referenced (the
+    paper's hit rule) iff its R became the new clock, and evicted iff its
+    slot was re-allocated while it was resident.  Exact int32 counts,
+    ``(0, 0)`` for a tree with no paged pool."""
+    hits = evictions = jnp.int32(0)
+    for a, b in zip(_pools(old_caches), _pools(new_caches)):
+        hits += jnp.sum((b.r == b.clock[..., None]) & (b.page_start >= 0),
+                        dtype=jnp.int32)
+        evictions += jnp.sum((b.page_start != a.page_start)
+                             & (a.page_start >= 0), dtype=jnp.int32)
+    return hits, evictions
 
 
 # ---------------------------------------------------------------------------
@@ -604,7 +628,6 @@ def _shard_wrap(fn, mesh, batch: int, example_args, n_batch_args: int):
     otherwise."""
     if mesh is None or batch % mesh.devices.size:
         return fn
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     rows = PartitionSpec(sharding.ROWS_AXIS)
@@ -616,8 +639,8 @@ def _shard_wrap(fn, mesh, batch: int, example_args, n_batch_args: int):
     out_specs = jax.tree.map(
         lambda s: rows if s.ndim == 1
         else PartitionSpec(sharding.ROWS_AXIS, *(None,) * (s.ndim - 1)), outs)
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def fused_decode_step(

@@ -390,8 +390,6 @@ def _sharded_groups_scan(
 
     Returns ``(T, Bs+Ba+Bc)`` hits in the unsharded path's
     group-concatenated row order."""
-    from jax.experimental.shard_map import shard_map
-
     n = mesh.devices.size
     rows_p = PartitionSpec(sharding.ROWS_AXIS)
     operands, specs_in, group_meta = [], [], []
@@ -466,14 +464,14 @@ def _sharded_groups_scan(
         _, hits = jax.lax.scan(step, carry0, xs_l, unroll=unroll)
         return hits
 
-    hits = shard_map(
+    hits = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=tuple(specs_in) + (PartitionSpec(None, None),),
         out_specs=tuple(
             PartitionSpec(None, sharding.ROWS_AXIS) for _ in group_meta
         ),
-        check_rep=False,
+        check_vma=False,
     )(*operands, xs)
     return jnp.concatenate(
         [h[:, :B] for h, (_, _, B) in zip(hits, group_meta)], axis=1

@@ -76,6 +76,7 @@ __all__ = [
     "PolicyCore",
     "make_core",
     "init",
+    "div_rn",
     "awrp_weights",
     "first_min",
     "awrp_victim_rows",
@@ -99,11 +100,87 @@ DEVICE_POLICIES = JAX_POLICIES + ADAPTIVE_POLICIES
 POLICY_IDS = {name: i for i, name in enumerate(DEVICE_POLICIES)}
 
 
+_MASK24 = (1 << 24) - 1
+
+
+def _mant_exp(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``x = m * 2**e`` exactly for a positive normal float32 ``x``: int32
+    ``(m, e)`` with ``2**23 <= m < 2**24``."""
+    b = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return (b & 0x7FFFFF) | 0x800000, ((b >> 23) & 0xFF) - 150
+
+
+def _mul24(a: jax.Array, d: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``a * d`` as base-2**24 limbs ``(hi, lo)`` in int32, for
+    ``0 <= a < 2**25`` and ``0 <= d < 2**24`` (12-bit partial products)."""
+    a0, a1 = a & 0xFFF, a >> 12
+    d0, d1 = d & 0xFFF, d >> 12
+    mid = a0 * d1 + a1 * d0
+    t = a0 * d0 + ((mid & 0xFFF) << 12)
+    return a1 * d1 + (mid >> 12) + (t >> 24), t & _MASK24
+
+
+def div_rn(x: jax.Array, y: jax.Array) -> jax.Array:
+    """``x / y`` in float32, rounded to nearest-even exactly as IEEE (and
+    numpy, so the host oracles) rounds it — on every backend.
+
+    The TPU's float32 divide is not correctly rounded, and every decision
+    here compares quotients bit for bit (AWRP's ``F/(N-R)``, ARC/CAR's
+    ``p`` step, the paged-KV reference threshold ``1/residents``), so a
+    one-ulp difference changes which block is evicted.  The backend's
+    quotient is corrected with exact integer arithmetic
+    (``_round_quotient``).
+
+    Domain: ``x >= 0`` and ``y > 0`` normal floats (``x`` may be 0) with a
+    normal quotient — every caller divides a count by a count >= 1."""
+    # one shape for both: a TPU kernel cannot bitcast a scalar
+    x, y = jnp.broadcast_arrays(jnp.asarray(x, jnp.float32),
+                                jnp.asarray(y, jnp.float32))
+    return _round_quotient(x, y, x / y)
+
+
+def _round_quotient(x: jax.Array, y: jax.Array, q0: jax.Array) -> jax.Array:
+    """``div_rn``'s correction: the float32 nearest ``x / y`` (ties to
+    even), given a quotient ``q0 = m_q 2**e_q`` within 2 ulp of it.
+
+    With ``u = 2**e_q``, ``x/y - q0 = u R / m_y`` for the integer ``R =
+    m_x 2**(e_x - e_y - e_q) - m_q m_y`` (exact: base-2**24 limbs, and
+    ``|R| < 2**26`` near ``q0``).  The floats from ``q0 - 2`` to ``q0 + 2``
+    ulp sit at known multiples of ``u/4`` (spacing halves below a power of
+    two), so ``x/y`` lies above the midpoint of two neighbours, ``S u/8``
+    from ``q0``, iff ``8 R > S m_y``.  The result is ``q0 - 2`` ulp plus
+    the number of those four midpoints below ``x/y`` (a quotient of two
+    floats is never a midpoint, so there are no ties to break)."""
+    b0 = jax.lax.bitcast_convert_type(q0, jnp.int32)
+    mx, ex = _mant_exp(x)
+    my, ey = _mant_exp(y)
+    mq, eq = _mant_exp(q0)
+    s = ex - ey - eq  # 22 <= s <= 25 near q0
+    hi_l = jnp.where(s < 24, mx >> jnp.clip(24 - s, 0, 31),
+                     mx << jnp.clip(s - 24, 0, 7))
+    lo_l = jnp.where(s < 24, (mx << jnp.clip(s, 0, 31)) & _MASK24, 0)
+    hi_r, lo_r = _mul24(mq, my)
+    r8 = ((hi_l - hi_r) * (1 << 24) + (lo_l - lo_r)) * 8
+    # neighbours' offsets from q0 in quarter-ulps: +4, +8 (+12 past the top
+    # of the binade), -4, -8 (-2, -4 / -4, -6 just above its bottom)
+    top = (mq == _MASK24).astype(jnp.int32)
+    bot0 = (mq == 1 << 23).astype(jnp.int32)
+    bot1 = (mq == (1 << 23) + 1).astype(jnp.int32)
+    pos_m1 = -4 + 2 * bot0
+    pos_m2 = -8 + 4 * bot0 + 2 * bot1
+    above = jnp.zeros_like(b0)
+    for mid in (pos_m2 + pos_m1, pos_m1, 4, 12 + 4 * top):
+        above = above + (r8 > mid * my).astype(jnp.int32)
+    bits = jnp.where(x > 0, b0 - 2 + above, 0)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
 def awrp_weights(f: jax.Array, r: jax.Array, clock: jax.Array) -> jax.Array:
-    """Paper eq. (1): W_i = F_i / (N - R_i), float32, residents only
-    (callers mask empties to +inf)."""
+    """Paper eq. (1): W_i = F_i / (N - R_i), float32 (``div_rn``, so
+    bitwise the host oracle's), residents only (callers mask empties to
+    +inf)."""
     dt = jnp.maximum(clock - r, 1).astype(jnp.float32)
-    return f.astype(jnp.float32) / dt
+    return div_rn(f.astype(jnp.float32), dt)
 
 
 # ---------------------------------------------------------------------------
@@ -206,9 +283,7 @@ def _flat_victim(
         v_awrp = awrp_victim_rows(row_f, row_r, clk, ~masks.dead, use_kernel=True)
         prim = jnp.where(masks.lfu, row_f, row_r)  # awrp rows: unused filler
     else:
-        w = row_f.astype(jnp.float32) / jnp.maximum(
-            clk[:, None] - row_r, 1
-        ).astype(jnp.float32)
+        w = awrp_weights(row_f, row_r, clk[:, None])
         wbits = jax.lax.bitcast_convert_type(w, jnp.int32)
         prim = jnp.where(
             masks.lru_or_fifo, row_r, jnp.where(masks.lfu, row_f, wbits)
@@ -344,6 +419,15 @@ def _keyed_head(tag: jax.Array, stamp: jax.Array, want: jax.Array) -> jax.Array:
     return in_list & (stamp == m)
 
 
+def _ghost_ratio(in_b1: jax.Array, n3f: jax.Array, n4f: jax.Array) -> jax.Array:
+    """The ghost-hit ratio of ARC/CAR's ``p`` step: ``|B2| / max(|B1|, 1)``
+    on a B1 hit, ``|B1| / max(|B2|, 1)`` otherwise (read only on a B2
+    hit) — one ``div_rn`` serves both directions."""
+    one = jnp.float32(1.0)
+    return div_rn(jnp.where(in_b1, n4f, n3f),
+                  jnp.maximum(jnp.where(in_b1, n3f, n4f), one))
+
+
 def _arc_step(
     blocks: jax.Array,  # (R, L) int32
     tag: jax.Array,  # (R, L) int32
@@ -372,10 +456,9 @@ def _arc_step(
     one = jnp.float32(1.0)
     capf = cap.astype(jnp.float32)
     n3f, n4f = n3.astype(jnp.float32), n4.astype(jnp.float32)
-    p_inc = jnp.minimum(capf, p + jnp.maximum(n4f / jnp.maximum(n3f, one), one))
-    p_dec = jnp.maximum(
-        jnp.float32(0.0), p - jnp.maximum(n3f / jnp.maximum(n4f, one), one)
-    )
+    step = jnp.maximum(_ghost_ratio(in_b1, n3f, n4f), one)
+    p_inc = jnp.minimum(capf, p + step)
+    p_dec = jnp.maximum(jnp.float32(0.0), p - step)
     p_new = jnp.where(in_b1, p_inc, jnp.where(in_b2, p_dec, p))
 
     # complete-miss directory maintenance + REPLACE trigger
@@ -518,10 +601,9 @@ def _car_step(
     one = jnp.float32(1.0)
     capf = cap.astype(jnp.float32)
     n3f, n4f = n3p.astype(jnp.float32), n4p.astype(jnp.float32)
-    p_inc = jnp.minimum(capf, p + jnp.maximum(one, n4f / jnp.maximum(n3f, one)))
-    p_dec = jnp.maximum(
-        jnp.float32(0.0), p - jnp.maximum(one, n3f / jnp.maximum(n4f, one))
-    )
+    step = jnp.maximum(one, _ghost_ratio(in_b1, n3f, n4f))
+    p_inc = jnp.minimum(capf, p + step)
+    p_dec = jnp.maximum(jnp.float32(0.0), p - step)
     p = jnp.where(in_b1, p_inc, jnp.where(in_b2, p_dec, p))
 
     stamp_x = (ctr + 1)[:, None]

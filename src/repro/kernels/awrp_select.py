@@ -9,8 +9,9 @@ with the bit-pattern min-reduction (argmin lowers to a ~30x slower scalar
 reduce on XLA CPU).
 
 Layout: metadata vectors are (B, P) int32 with P padded to the 128-lane
-boundary by the ops.py wrapper; grid is (B,) — one program per sequence
-(policy instances are independent, so the grid parallelizes freely).
+boundary by the ops.py wrapper.  ``awrp_select_kernel`` runs one program
+per sequence; ``awrp_select_rows_kernel`` one program per tile of rows
+(policy instances are independent, so either grid parallelizes freely).
 """
 
 from __future__ import annotations
@@ -19,18 +20,19 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.policy_core import awrp_weights
+
 
 def _masked_weight_first_min(f, r, clock_col, mask):
-    """Shared victim-select body for both kernel variants: paper eq. (1) in
-    the host oracle's exact float32 ops (bit-exact decisions), then the
-    first-index minimum over masked lanes as two vectorizable integer
-    min-reductions.  w >= 0 always (F >= 0, dt >= 1), and non-negative IEEE
+    """Shared victim-select body for both kernel variants: paper eq. (1)
+    rounded exactly as the host oracle rounds it (``awrp_weights``, so
+    decisions are bit-exact on the TPU too), then the first-index minimum
+    over masked lanes as two vectorizable integer min-reductions.  w >= 0 always (F >= 0, dt >= 1), and non-negative IEEE
     floats order identically to their int32 bit patterns — so no argmin
     (XLA CPU lowers a float argmin to a ~30x slower scalar reduce; TPU
     dislikes 1D iota)."""
     P = f.shape[-1]
-    dt = jnp.maximum(clock_col - r, 1).astype(jnp.float32)
-    w = f.astype(jnp.float32) / dt
+    w = awrp_weights(f, r, clock_col)
     bits = jax.lax.bitcast_convert_type(w, jnp.int32)
     bits = jnp.where(mask, bits, jnp.iinfo(jnp.int32).max)
     lane = jax.lax.broadcasted_iota(jnp.int32, bits.shape, 1)
@@ -79,31 +81,47 @@ def awrp_select_kernel(
 
 
 def _rows_kernel(f_ref, r_ref, clock_ref, valid_ref, out_ref):
-    f = f_ref[...]  # (B, P) int32
-    r = r_ref[...]
-    clock = clock_ref[...]  # (B,) int32
-    valid = valid_ref[...] != 0
-    out_ref[...] = _masked_weight_first_min(f, r, clock[:, None], valid)
+    valid = valid_ref[...] != 0  # (TB, P)
+    out_ref[...] = _masked_weight_first_min(
+        f_ref[...], r_ref[...], clock_ref[...], valid)[:, None]
+
+
+#: bytes of one (row tile, P) int32 metadata block: three inputs, double
+#: buffered, plus the kernel's temporaries stay a few MiB under the 16 MiB
+#: scoped-VMEM default at any B
+_ROW_TILE_BYTES = 512 * 1024
+
+
+def rows_tile(B: int, P: int) -> int:
+    """Rows per grid step of ``awrp_select_rows_kernel``: a multiple of 8
+    (the sublane tile) holding at most ``_ROW_TILE_BYTES`` of one plane, and
+    no more rows than ``B`` rounded up to 8."""
+    cap = max(8, (_ROW_TILE_BYTES // (4 * P)) // 8 * 8)
+    return min(cap, -(-B // 8) * 8)
 
 
 def awrp_select_rows_kernel(
-    f: jax.Array,  # (B, P) int32, P % 128 == 0
+    f: jax.Array,  # (B, P) int32, P % 128 == 0, B % rows_tile(B, P) == 0
     r: jax.Array,  # (B, P) int32
-    clock: jax.Array,  # (B,) int32
+    clock: jax.Array,  # (B, 1) int32
     valid: jax.Array,  # (B, P) int32 (0/1)
     *,
+    tile: int,
     interpret: bool = False,
 ) -> jax.Array:
-    """Rows variant: all B policy instances in ONE grid program.
-
-    Used by the batched sweep engine, which calls this once per trace step
-    with B = the whole (trace, policy, capacity) grid — the metadata for every
-    cache in the sweep sits in VMEM together, so one VPU pass computes every
-    victim.  The per-row-program variant above stays for serving, where B is
-    large and rows are independent."""
-    B, _ = f.shape
+    """Rows variant for the batched sweep engine, called once per trace step
+    with B = the whole (trace, policy, capacity) grid: a grid over row
+    tiles of ``tile`` rows, so VMEM use is bounded at any B and each step
+    computes a whole tile's victims in one VPU pass.  Returns ``(B, 1)``;
+    ``ops.awrp_select_rows`` pads B and P and squeezes the result."""
+    B, P = f.shape
+    plane = pl.BlockSpec((tile, P), lambda i: (i, 0))
+    col = pl.BlockSpec((tile, 1), lambda i: (i, 0))
     return pl.pallas_call(
         _rows_kernel,
-        out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
+        grid=(B // tile,),
+        in_specs=[plane, plane, col, plane],
+        out_specs=col,
+        out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
     )(f, r, clock, valid)

@@ -1,8 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handles TPU-friendly padding (lane-aligned page counts, MXU-aligned seq
-tiles) and the interpret-mode fallback used on CPU (this container) — on a
-real TPU set ``interpret=False`` (the default resolves via backend check).
+tiles) and the execution mode: ``interpret=None`` compiles the kernel for
+the TPU when JAX's backend is ``tpu``, runs the Pallas interpreter when it
+is ``cpu`` (the test suite's ``JAX_PLATFORMS=cpu``), and raises on any
+other backend rather than silently interpreting there.
 
 Policy callers never import these directly: victim selection routes through
 the unified core's dispatch (``repro.core.policy_core.awrp_victim_rows``,
@@ -16,7 +18,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.awrp_select import awrp_select_kernel, awrp_select_rows_kernel
+from repro.kernels.awrp_select import (
+    awrp_select_kernel,
+    awrp_select_rows_kernel,
+    rows_tile,
+)
 from repro.kernels.flash_attn import flash_attention_kernel
 from repro.kernels.paged_attn import paged_attention_kernel
 from repro.kernels.policy_attn import (
@@ -27,7 +33,12 @@ from repro.obs import profiling
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels here compile for TPU or interpret on CPU; the "
+            f"JAX backend is {backend!r} (pass interpret= explicitly)")
+    return backend == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -51,22 +62,24 @@ def awrp_select(f, r, clock, valid, pinned, *, interpret: bool | None = None):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def awrp_select_rows(f, r, clock, valid, *, interpret: bool | None = None):
-    """(B, P) int32 metadata -> (B,) int32 victims, all rows in one program.
+    """(B, P) int32 metadata -> (B,) int32 victims, one grid step per tile
+    of rows (``awrp_select.rows_tile``).
 
     The batched sweep engine's victim-selection hot path: called once per
     trace step with B = the flattened (trace, policy, capacity) grid."""
     if interpret is None:
         interpret = _default_interpret()
-    P = f.shape[1]
+    B, P = f.shape
     pad = (-P) % 128  # lane alignment
-    if pad:
-        f = jnp.pad(f, ((0, 0), (0, pad)))
-        r = jnp.pad(r, ((0, 0), (0, pad)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad)))  # padded slots invalid
+    tile = rows_tile(B, P + pad)
+    rpad = (-B) % tile  # whole row tiles; padded rows are sliced off
+    pads = ((0, rpad), (0, pad))
     return awrp_select_rows_kernel(
-        f.astype(jnp.int32), r.astype(jnp.int32), clock.astype(jnp.int32),
-        valid.astype(jnp.int32), interpret=interpret,
-    )
+        jnp.pad(f.astype(jnp.int32), pads), jnp.pad(r.astype(jnp.int32), pads),
+        jnp.pad(clock.astype(jnp.int32), (0, rpad))[:, None],
+        jnp.pad(valid.astype(jnp.int32), pads),  # padded slots invalid
+        tile=tile, interpret=interpret,
+    )[:B, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

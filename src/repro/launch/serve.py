@@ -1,18 +1,22 @@
 """Serving driver: batched requests through the AWRP-managed engine.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma3_27b \
-      --requests 8 --new-tokens 32 --kv-mode paged --kv-policy awrp
+The published configuration of ``--arch`` is served at full width with
+random weights; ``--smoke`` swaps in the arch's reduced
+``SMOKE_CONFIG`` (CPU-sized):
+
+  PYTHONPATH=src python -m repro.launch.serve --arch smollm_360m \
+      --requests 8 --new-tokens 32 --kv-mode paged --kv-policy awrp --fused
 
 Multi-tenant serving (DESIGN.md §8) — one policy-core row per tenant,
 per-tenant quotas/telemetry and pressure-driven admission:
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma3_27b \
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma3_27b --smoke \
       --requests 8 --tenants "alice=4,bob=2" --repeat-prompts
 
 Performance observability (DESIGN.md §12) — live Prometheus endpoint,
 periodic JSONL snapshots, and jax.profiler trace capture:
 
-  PYTHONPATH=src python -m repro.launch.serve --arch gemma3_27b \
+  PYTHONPATH=src python -m repro.launch.serve --arch gemma3_27b --smoke \
       --requests 8 --metrics-port 0 --metrics-out /tmp/serve_metrics \
       --snapshot-every 2 --profile-dir /tmp/serve_prof --profile-phases
 """
@@ -26,14 +30,21 @@ import time
 import jax
 import numpy as np
 
-from repro.configs.base import ARCH_IDS, load_smoke_config
+from repro.configs.base import ARCH_IDS, load_config, load_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve.engine import Request, ServeEngine
 
 
-def main():
+def main(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="gemma3_27b", choices=ARCH_IDS)
+    ap.add_argument("--arch", default="smollm_360m", choices=ARCH_IDS)
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's reduced SMOKE_CONFIG instead of "
+                    "the published CONFIG")
+    ap.add_argument("--fused", action="store_true",
+                    help="paged KV only: decode through the fused "
+                    "policy-attention Pallas kernel (DESIGN.md §10)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--new-tokens", type=int, default=32)
     ap.add_argument("--prompt-len", type=int, default=64)
@@ -85,7 +96,8 @@ def main():
                     help="sync-disciplined phase timers: each span blocks "
                     "on its own outputs so span/* isolates per-phase "
                     "device time (obs.spans sync discipline)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     tenants = None
     if args.tenants:
@@ -94,7 +106,7 @@ def main():
             name, _, quota = part.partition("=")
             tenants[name.strip()] = int(quota)
 
-    cfg = load_smoke_config(args.arch)
+    cfg = (load_smoke_config if args.smoke else load_config)(args.arch)
     cfg = dataclasses.replace(cfg, kv_policy=args.kv_policy)
     params = M.init_params(cfg, jax.random.PRNGKey(0))
     if args.decision_trace and tenants is None:
@@ -104,7 +116,7 @@ def main():
     engine = ServeEngine(cfg, params, max_len=args.max_len,
                          kv_mode=args.kv_mode, tenants=tenants,
                          auto_rebalance=args.auto_rebalance,
-                         jit_loop=not args.host_loop,
+                         jit_loop=not args.host_loop, fused=args.fused,
                          decision_trace=args.decision_trace,
                          profile_dir=args.profile_dir,
                          profile_every=args.profile_every,
@@ -153,7 +165,7 @@ def main():
     total_tokens = sum(len(r.tokens) for r in results.values())
     loop = "host" if args.host_loop else "jit"
     print(f"arch={cfg.name} kv_mode={args.kv_mode} policy={args.kv_policy} "
-          f"loop={loop}")
+          f"loop={loop} fused={args.fused}")
     print(f"{len(reqs)} requests, {total_tokens} tokens in {dt:.2f}s "
           f"({total_tokens/dt:.1f} tok/s host-side)")
     if args.decision_trace:

@@ -91,10 +91,15 @@ def count_eqns(jaxpr) -> int:
 
 def _abstract(x: Any) -> Any:
     """Array leaves -> ``ShapeDtypeStruct`` (no buffer retained; the lazy
-    audit re-traces with these), everything else passes through (static
-    operands, python scalars, meshes)."""
+    audit and ``compiled_text`` re-trace with these), keeping the placement
+    of arrays committed to one (so the re-lowered program is the one that
+    ran); everything else passes through (static operands, python scalars,
+    meshes)."""
     if isinstance(x, (jax.Array, np.ndarray)):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+        concrete = not isinstance(x, jax.core.Tracer)
+        sharding = x.sharding if concrete and getattr(
+            x, "committed", False) else None
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
     return x
 
 
@@ -124,6 +129,7 @@ class Sentinel:
         self.eqns = 0
         self._jits: list = []
         self._pending: Optional[tuple] = None
+        self._last: Optional[tuple] = None
         _ALL.add(self)
 
     @property
@@ -155,12 +161,10 @@ class Sentinel:
             self.calls += 1
             if self.traces != before:
                 self.last_trace_s = time.perf_counter() - t0
+                self._last = (jfn, jax.tree.map(_abstract, a),
+                              jax.tree.map(_abstract, k))
                 if audit_eqns:
-                    self._pending = (
-                        jfn,
-                        jax.tree.map(_abstract, a),
-                        jax.tree.map(_abstract, k),
-                    )
+                    self._pending = self._last
             return out
 
         call._cache_size = jfn._cache_size
@@ -180,6 +184,17 @@ class Sentinel:
             self.eqns = count_eqns(jfn.trace(*a, **k).jaxpr)
         except Exception:  # noqa: BLE001 — audit must never break serving
             self.eqns = -1
+
+    def compiled_text(self) -> str:
+        """Optimized HLO text of the most recently traced program, compiled
+        again from its abstract arguments (a persistent-cache hit when the
+        compilation cache is on).  Shows what the compiler made of the
+        program, e.g. whether a Pallas kernel lowered to
+        ``tpu_custom_call``."""
+        if self._last is None:
+            raise RuntimeError(f"{self.name}: nothing traced yet")
+        jfn, a, k = self._last
+        return jfn.lower(*a, **k).compile().as_text()
 
     def metrics(self) -> Dict[str, Any]:
         """This sentinel's gauge dict (resolves any pending audit)."""
@@ -236,9 +251,9 @@ class TraceCapture:
     ``maybe(n)`` is the per-``generate`` hook: a context manager that
     either runs the body inside ``jax.profiler.trace`` +
     ``StepTraceAnnotation`` (when the request counter crosses a capture
-    boundary) or is a no-op.  Capture failures (an already-active
-    profiler session, an unwritable directory) degrade to the no-op path
-    — profiling must never take serving down."""
+    boundary) or is a no-op.  A capture that cannot start (an
+    already-active profiler session, an unwritable directory) raises: a
+    trace that ``profile_dir`` asked for is never dropped in silence."""
 
     def __init__(self, profile_dir: str, every: int = 16):
         self.dir = str(profile_dir)
@@ -258,11 +273,7 @@ class TraceCapture:
         if not due:
             yield False
             return
-        try:
-            jax.profiler.start_trace(self.dir)
-        except Exception:  # noqa: BLE001 — e.g. a session already active
-            yield False
-            return
+        jax.profiler.start_trace(self.dir)
         try:
             with jax.profiler.StepTraceAnnotation(
                 "generate", step_num=self.captures

@@ -40,15 +40,10 @@ _DTYPE_BYTES = {
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` normalized to a flat dict.
+    """``compiled.cost_analysis()`` as a plain dict (empty when the backend
+    reports nothing)."""
+    return dict(compiled.cost_analysis() or {})
 
-    jax <= 0.4.x returns a one-entry list of dicts (one per partitioned
-    program); newer jax returns the dict directly.  Callers should always go
-    through this."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
 
 _SHAPE_RE = re.compile(r"(\w+)\[([\d,]*)\]")
 _COLLECTIVE_RE = re.compile(

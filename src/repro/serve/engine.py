@@ -97,6 +97,12 @@ def _is_apool(x) -> bool:
     return isinstance(x, AdaptivePagedPool)
 
 
+def _kv_step(old_caches, new_caches) -> jax.Array:
+    """One decode step's paged-KV ``[hits, evictions]`` (int32, bounded by
+    layers x sequences x pages); the engine sums the steps on the host."""
+    return jnp.stack(paged_kv.step_counts(old_caches, new_caches))
+
+
 class ServeEngine:
     """Continuous-batching serving engine over AWRP-managed caches.
 
@@ -203,6 +209,12 @@ class ServeEngine:
         self.metrics = bool(metrics)
         self._planes = loop_planes() if self.metrics else None
         self._fold = jax.jit(functools.partial(loop_update, vocab=cfg.vocab))
+        #: paged-KV page hits (paper reference rule) and evictions, summed
+        #: over layers, sequences and decode steps: the decode loops emit
+        #: per-step counts and the host adds them here as Python ints, so
+        #: the totals never wrap however long the engine serves
+        self._kv_counts = {"hits": 0, "evictions": 0}
+        self._kv_step = jax.jit(_kv_step)
         #: host timing spans around the serving sections (prefill / decode /
         #: rebalance / trace_drain) — mounted on the registry like the
         #: caches.  ``profile_phases=True`` turns on the sync discipline:
@@ -276,7 +288,8 @@ class ServeEngine:
             # folded after every sampling event (first greedy token
             # included) by the same jitted `loop_update` the host loop
             # applies per step, so the planes are bit-identical across the
-            # loop modes (integer adds / scatter-adds only)
+            # loop modes (integer adds / scatter-adds only).  The paged-KV
+            # counts leave the scan per step (``_kv_step``)
             def loop(params, logits, caches, key, temperature, planes):
                 toks = sample(logits[:, -1:], key, temperature=0.0,
                               vocab=cfg.vocab)
@@ -285,19 +298,19 @@ class ServeEngine:
                 def body(carry, _):
                     t, c, k, pl = carry
                     k, sub = jax.random.split(k)
-                    lg, c = M.decode_step(params, cfg, t, c, kv_mode=kv_mode,
-                                          fused=fused, mesh=mesh)
+                    lg, c2 = M.decode_step(params, cfg, t, c, kv_mode=kv_mode,
+                                           fused=fused, mesh=mesh)
                     t = sample_traced(lg, sub, temperature, vocab=cfg.vocab)
                     pl = loop_update(pl, t, vocab=cfg.vocab)
-                    return (t, c, k, pl), t
+                    return (t, c2, k, pl), (t, _kv_step(c, c2))
 
-                (_, caches, key, planes), ys = jax.lax.scan(
+                (_, caches, key, planes), (ys, kv) = jax.lax.scan(
                     body, (toks, caches, key, planes), None, length=steps - 1
                 )
                 gen = jnp.concatenate(
                     [toks, jnp.moveaxis(ys[..., 0], 0, 1)], axis=1
                 )
-                return gen, caches, key, planes
+                return gen, caches, key, planes, kv
 
             return self._loop_sentinel.wrap(loop, donate_argnums=(2, 3, 5))
 
@@ -420,11 +433,20 @@ class ServeEngine:
             }
         return out
 
+    def _add_kv_counts(self, per_step) -> None:
+        """Fold ``(steps, 2)`` per-step ``[hits, evictions]`` into the
+        engine's Python-int totals (one pull, after the tokens' own)."""
+        hits, evictions = np.asarray(per_step, np.int64).reshape(-1, 2).sum(0)
+        self._kv_counts["hits"] += int(hits)
+        self._kv_counts["evictions"] += int(evictions)
+
     def _kv_provider(self) -> dict:
         if self.kv_mode != "paged":
             return {}
         out: dict = {"pool": {"policy": self.cfg.kv_policy,
                               "pages": self.cfg.bounded_kv_pages}}
+        if self.metrics:
+            out["pool"].update(self._kv_counts)
         for t, states in self._kv_sessions.items():
             tel = [paged_kv.pool_telemetry(s) for s in states]
             out[t] = {
@@ -670,7 +692,7 @@ class ServeEngine:
             # additionally blocks on the caches (obs.spans docstring)
             with self.spans.span("decode") as sp:
                 if self.metrics:
-                    gen_dev, caches, self.key, self._planes = loop(
+                    gen_dev, caches, self.key, self._planes, kv = loop(
                         self.params, logits, caches, self.key,
                         jnp.float32(reqs[0].temperature), self._planes)
                 else:
@@ -679,6 +701,8 @@ class ServeEngine:
                         jnp.float32(reqs[0].temperature))
                 sp.ready(caches)
                 gen = np.asarray(gen_dev)
+            if self.metrics:
+                self._add_kv_counts(kv)
             self.stats["decode_steps"] += max_new - 1
         else:
             with self.spans.span("decode"):
@@ -686,19 +710,23 @@ class ServeEngine:
                               vocab=self.cfg.vocab)
                 if self.metrics:
                     self._planes = self._fold(self._planes, toks)
-                generated = [toks]
+                generated, kv = [toks], []
                 for step in range(max_new - 1):
                     self.key, sub = jax.random.split(self.key)
+                    prev = caches
                     logits, caches = self._decode(self.params, toks, caches)
                     toks = sample(logits, sub,
                                   temperature=reqs[0].temperature,
                                   vocab=self.cfg.vocab)
                     if self.metrics:
                         self._planes = self._fold(self._planes, toks)
+                        kv.append(self._kv_step(prev, caches))
                     generated.append(toks)
                     self.stats["decode_steps"] += 1
                 gen = np.concatenate(
                     [np.asarray(t) for t in generated], axis=1)
+            if self.metrics and kv:
+                self._add_kv_counts(jnp.stack(kv))
         if single and self._ghost_feed_on:
             self._kv_persist(caches, reqs[0].tenant_id)
         dt = time.time() - t0

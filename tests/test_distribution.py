@@ -27,9 +27,10 @@ from repro.sharding.specs import activate, make_rules
 from repro.optim import optimizer as O
 from repro.train.train_step import make_train_step
 
-# no axis_types kwarg: jax.sharding.AxisType only exists on newer jax, and
-# Auto is the default mesh axis semantics anyway
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+# Auto axes: the sharding rules place activations by constraint, and
+# make_mesh defaults to Explicit axes, under which gathers reject them
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 results = {}
 for arch in ("qwen25_14b", "zamba2_7b", "phi35_moe"):
     cfg = load_smoke_config(arch)

@@ -205,3 +205,34 @@ def test_pool_eviction_matches_core_awrp_oracle():
             pin = int(np.asarray(prev.open_slot)[0])
             w[pin] = np.inf
             assert int(np.argmin(w)) == int(np.flatnonzero(changed)[0])
+
+
+@pytest.mark.parametrize("policy", ["awrp", "lru"])
+def test_step_counts_match_reference_rule_and_evictions(policy):
+    """``step_counts`` counts exactly the pages the reference rule marked
+    and one eviction per page-boundary allocation into a full pool."""
+    B, P, page, kvd = 2, 3, 4, 8
+    pool = paged_kv.init_pool(B, P, page, kvd, jnp.float32)
+    key = jax.random.PRNGKey(2)
+    total_ev = 0
+    for pos in range(P * page + 2 * page + 1):
+        key, k1, k2 = jax.random.split(key, 3)
+        row = jax.random.normal(k1, (B, kvd))
+        ins = paged_kv.insert_token(pool, row, row, jnp.int32(pos), page,
+                                    policy=policy)
+        mass = jax.random.uniform(k2, (B, P * page)) / page
+        new = paged_kv.score_update(ins, mass, page)
+        hits, ev = paged_kv.step_counts(pool, new)
+        want = paged_kv.referenced_pages(ins, mass, page).sum()
+        assert int(hits) == int(want)
+        full_alloc = pos % page == 0 and pos >= P * page
+        assert int(ev) == (B if full_alloc else 0), pos
+        total_ev += int(ev)
+        pool = new
+    assert total_ev == 3 * B  # allocations at P*page, +page, +2*page
+    # a tree holding the pool twice counts it twice; no pool counts nothing
+    h2, e2 = paged_kv.step_counts({"a": pool, "b": [pool]},
+                                  {"a": pool, "b": [pool]})
+    assert int(e2) == 0 and int(h2) == 2 * int(paged_kv.step_counts(
+        pool, pool)[0])
+    assert tuple(map(int, paged_kv.step_counts({}, {}))) == (0, 0)

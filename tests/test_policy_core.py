@@ -17,6 +17,8 @@ from repro.core.policy_core import (
     POLICY_IDS,
     AdaptiveCore,
     FlatCore,
+    _round_quotient,
+    div_rn,
     init,
     make_core,
 )
@@ -45,6 +47,53 @@ def drive(core, state, streams):
         state, h = step(state, streams[:, t])
         hits.append(np.asarray(h))
     return state, np.stack(hits, axis=1)
+
+
+# ---------------------------------------------------------------------------
+# exact float32 division (the TPU divide is not correctly rounded)
+# ---------------------------------------------------------------------------
+
+
+def _division_operands():
+    """Every count pair up to 700, plus random counts up to 2**24 and large
+    normal floats: the decision quotients' domain and beyond it."""
+    a = np.arange(700, dtype=np.float32)
+    x, y = np.meshgrid(a, a + 1)
+    rng = np.random.RandomState(0)
+    xr = rng.randint(0, 1 << 24, 200_000).astype(np.float32)
+    yr = rng.randint(1, 1 << 24, 200_000).astype(np.float32)
+    xf = (rng.rand(20_000) * 2.0**40).astype(np.float32)
+    yf = (rng.rand(20_000) * 2.0**20 + 1).astype(np.float32)
+    # quotients at and next to powers of two, where the float spacing
+    # changes: y * 2**k and its neighbours
+    ye = rng.randint(1, 1 << 24, 4_000).astype(np.float32)
+    bits = (ye * np.float32(2.0**rng.randint(-8, 9, ye.size))).view(np.int32)
+    xe = (bits[:, None] + np.arange(-3, 4)).astype(np.int32).view(np.float32)
+    return (np.concatenate([x.ravel(), xr, xf, xe.ravel()]),
+            np.concatenate([y.ravel(), yr, yf, np.repeat(ye, 7)]))
+
+
+def test_div_rn_is_ieee_division():
+    import jax
+
+    x, y = _division_operands()
+    got = np.asarray(jax.jit(div_rn)(x, y))
+    np.testing.assert_array_equal(got.view(np.int32), (x / y).view(np.int32))
+
+
+@pytest.mark.parametrize("ulps", [-2, -1, 1, 2])
+def test_div_rn_corrects_an_inexact_quotient(ulps):
+    """What a TPU divide returns — a quotient a few ulp off — comes out
+    correctly rounded: ``_round_quotient`` is fed IEEE quotients shifted by
+    ``ulps`` (where that stays positive)."""
+    import jax
+
+    x, y = _division_operands()
+    want = x / y
+    bits = want.view(np.int32)
+    q0 = np.where(x > 0, bits + ulps, bits).astype(np.int32).view(np.float32)
+    got = np.asarray(jax.jit(_round_quotient)(x, y, q0))
+    np.testing.assert_array_equal(got.view(np.int32), bits)
 
 
 # ---------------------------------------------------------------------------

@@ -100,6 +100,21 @@ def test_instrument_decorator_with_static_argnames():
     assert scale.sentinel.traces == 2 and scale.sentinel.calls == 3
 
 
+def test_compiled_text_is_the_last_traced_program():
+    """``compiled_text`` re-lowers the last traced call's abstract
+    arguments: the program that ran, shapes and all."""
+    @instrument("t_text")
+    def double(x):
+        return x * 2
+
+    with pytest.raises(RuntimeError, match="nothing traced"):
+        double.sentinel.compiled_text()
+    double(jnp.ones((3,)))
+    double(jnp.ones((5, 7)))  # retrace: the newest program wins
+    text = double.sentinel.compiled_text()
+    assert "f32[5,7]" in text and "f32[3]" not in text
+
+
 def test_instrument_donate_argnums_preserved():
     @instrument("t_donate", donate_argnums=(0,))
     def bump(x):
@@ -222,6 +237,20 @@ def test_trace_capture_cadence_and_files(tmp_path):
     assert cap.metrics()["captures"] == 3
     # the profiler actually wrote a trace directory
     assert any((tmp_path / "prof").rglob("*"))
+
+
+def test_trace_capture_that_cannot_start_raises(tmp_path):
+    """A capture ``profile_dir`` asked for is never dropped in silence: with
+    another profiler session active, the due capture raises."""
+    cap = TraceCapture(str(tmp_path / "prof"), every=1)
+    jax.profiler.start_trace(str(tmp_path / "other"))
+    try:
+        with pytest.raises(RuntimeError, match="already been started"):
+            with cap.maybe(1):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    assert cap.captures == 0
 
 
 # ---------------------------------------------------------------------------

@@ -276,6 +276,31 @@ def test_bounded_kv_true_adaptive_engine_runs_past_pool_capacity(kv_policy):
     assert eng.telemetry()["kv/pool/policy"] == kv_policy
 
 
+@pytest.mark.parametrize("jit_loop", [True, False])
+def test_kv_pool_counters_do_not_wrap_past_int32(jit_loop):
+    """``kv/pool/hits`` and ``kv/pool/evictions`` keep counting past 2**31
+    (a long-lived engine): each generate's per-step counts are added on the
+    host, so a total near the int32 limit grows by exactly what a fresh
+    engine counts for the same requests."""
+    cfg = load_smoke_config("gemma3_27b")
+    cfg = dataclasses.replace(cfg, dtype="float32", param_dtype="float32",
+                              bounded_kv_pages=3, page_size=8)
+    params = M.init_params(cfg, jax.random.PRNGKey(1))
+
+    def counts(start):
+        eng = ServeEngine(cfg, params, max_len=128, kv_mode="paged",
+                          jit_loop=jit_loop)
+        eng._kv_counts.update(hits=start, evictions=start)
+        eng.generate([Request(0, list(range(1, 17)), max_new_tokens=30)])
+        tel = eng.telemetry()
+        return tel["kv/pool/hits"], tel["kv/pool/evictions"]
+
+    hits, evictions = counts(0)
+    assert hits > 0 and evictions > 0
+    start = 2**31 - 1
+    assert counts(start) == (start + hits, start + evictions)
+
+
 # ---------------------------------------------------------------------------
 # multi-tenant serving (serve.tenancy, DESIGN.md §8)
 # ---------------------------------------------------------------------------
@@ -503,3 +528,17 @@ def test_ghost_hit_feed_adapts_p_under_prefix_reuse():
     assert t["kv/a/ghost_hits"] > 0  # the feed fired
     assert eng.stats["kv_ghost_hits"] == t["kv/a/ghost_hits"]
     assert max(p_max) > 0.0  # p moved (provably static in pure decode)
+
+
+def test_serve_cli_main_runs_in_process(monkeypatch, capsys):
+    """``launch.serve.main(argv)`` serves in the calling process; ``--smoke``
+    loads the reduced config and ``--fused`` reaches the engine."""
+    from repro.launch import serve
+
+    monkeypatch.setattr(serve, "enable_compile_cache", lambda: "")
+    serve.main(["--arch", "smollm_360m", "--smoke", "--requests", "2",
+                "--new-tokens", "3", "--prompt-len", "64", "--kv-mode",
+                "paged", "--fused"])
+    out = capsys.readouterr().out
+    assert "kv_mode=paged policy=awrp loop=jit fused=True" in out
+    assert "2 requests, 6 tokens" in out
