@@ -236,11 +236,18 @@ def init_set_state(
     )
 
 
+#: ``jax.named_scope`` of each policy core's call in the sweep scan step: a
+#: compiled op's ``op_name`` names the core it belongs to, which
+#: ``Sentinel.op_scopes`` reads (scope metadata only; the program is unchanged)
+CORE_SCOPES = {"flat": "sweep_flat", "arc": "sweep_arc", "car": "sweep_car"}
+
+
 # sentinel-wrapped jit (obs.profiling): the sweep scan's trace count,
 # cache size and jaxpr eqn audit surface as compile/sweep_scan/... gauges
 @functools.partial(
     profiling.instrument,
     "sweep_scan",
+    scopes=tuple(CORE_SCOPES.values()),
     static_argnames=(
         "policy_ids", "ways", "num_sets", "use_kernel", "unroll", "renorm_at",
         "mesh",
@@ -331,13 +338,19 @@ def _simulate_batched_impl(
         block = jnp.repeat(block_n, PC)
         outs = []
         if flat_core is not None:
-            flat_st, h = flat_core.on_access(flat_st, block[take_s])
+            ids = block[take_s]
+            with jax.named_scope(CORE_SCOPES["flat"]):
+                flat_st, h = flat_core.on_access(flat_st, ids)
             outs.append(h)
         if arc_core is not None:
-            arc_st, h = arc_core.on_access(arc_st, block[take_a])
+            ids = block[take_a]
+            with jax.named_scope(CORE_SCOPES["arc"]):
+                arc_st, h = arc_core.on_access(arc_st, ids)
             outs.append(h)
         if car_core is not None:
-            car_st, h = car_core.on_access(car_st, block[take_c])
+            ids = block[take_c]
+            with jax.named_scope(CORE_SCOPES["car"]):
+                car_st, h = car_core.on_access(car_st, ids)
             outs.append(h)
         hits = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
         return (flat_st, arc_st, car_st), hits
@@ -452,10 +465,11 @@ def _sharded_groups_scan(
             new_states, outs = [], []
             for g, (kind, tmpl, _) in enumerate(group_meta):
                 ids = block_n[ops[3 * g + 2]]
-                if kind == "flat":
-                    st, h = tmpl.on_access(carry[g], ids, masks=ops[3 * g + 1])
-                else:
-                    st, h = tmpl.on_access(carry[g], ids, caps=ops[3 * g + 1])
+                with jax.named_scope(CORE_SCOPES[kind]):
+                    if kind == "flat":
+                        st, h = tmpl.on_access(carry[g], ids, masks=ops[3 * g + 1])
+                    else:
+                        st, h = tmpl.on_access(carry[g], ids, caps=ops[3 * g + 1])
                 new_states.append(st)
                 outs.append(h)
             return tuple(new_states), tuple(outs)

@@ -77,6 +77,7 @@ def awrp_select_kernel(
         out_specs=pl.BlockSpec((1,), lambda b: (b,)),
         out_shape=jax.ShapeDtypeStruct((B,), jnp.int32),
         interpret=interpret,
+        name="awrp_select",
     )(f, r, clock, valid, pinned)
 
 
@@ -124,4 +125,5 @@ def awrp_select_rows_kernel(
         out_specs=col,
         out_shape=jax.ShapeDtypeStruct((B, 1), jnp.int32),
         interpret=interpret,
+        name="awrp_select_rows",
     )(f, r, clock, valid)

@@ -125,5 +125,6 @@ def flash_attention_kernel(
             pltpu.VMEM((G, block_q, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3, 4)  # (B, Sq, KVH, G, hd)

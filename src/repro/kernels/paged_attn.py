@@ -149,4 +149,5 @@ def paged_attention_kernel(
         ],
         scratch_shapes=flash_scratch(KVH, G, hd, P),
         interpret=interpret,
+        name="paged_attention",
     )(q, k_pages, v_pages, page_start, cur_pos)

@@ -176,12 +176,13 @@ def _row_block(x):
     return pl.BlockSpec((None,) + tuple(x.shape[1:]), lambda b, p: (b, 0, 0))
 
 
-def _fused_call(kern, q, k_pages, v_pages, new_k, new_v, pos, rows_in,
-                rows_out, scratch, interpret):
-    """``pallas_call`` of a fused kernel over grid ``(B, P)``.  ``rows_in``
-    are the per-sequence policy operands, ``(B, N)`` planes or ``(B,)``
-    scalars, passed as ``(B, 1, N)`` / ``(B, 1, 1)``; ``rows_out`` lists
-    each per-sequence output as ``(N or None for a scalar, dtype)``.
+def _fused_call(name, kern, q, k_pages, v_pages, new_k, new_v, pos,
+                rows_in, rows_out, scratch, interpret):
+    """``pallas_call``, named ``name``, of a fused kernel over grid
+    ``(B, P)``.  ``rows_in`` are the per-sequence policy operands, ``(B, N)``
+    planes or ``(B,)`` scalars, passed as ``(B, 1, N)`` / ``(B, 1, 1)``;
+    ``rows_out`` lists each per-sequence output as ``(N or None for a
+    scalar, dtype)``.
     Returns the attention output followed by the policy outputs in their
     ``(B, N)`` / ``(B,)`` shapes."""
     B, P, page, KVH, hd = k_pages.shape
@@ -201,6 +202,7 @@ def _fused_call(kern, q, k_pages, v_pages, new_k, new_v, pos, rows_in,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype)] + outs,
         scratch_shapes=flash_scratch(KVH, G, hd, P) + scratch,
         interpret=interpret,
+        name=name,
     )(pos, q, k_pages, v_pages, new_k, new_v, *ins)
     return (res[0],) + tuple(x.reshape(B, n) if n else x.reshape(B)
                              for x, (n, _) in zip(res[1:], rows_out))
@@ -232,6 +234,7 @@ def policy_paged_attention_kernel(
                              n_pages=P, policy=policy)
     i32 = jnp.int32
     return _fused_call(
+        "policy_paged_attention",
         kern, q, k_pages, v_pages, new_k, new_v, pos,
         [f, r, page_start, clock, open_slot],
         [(P, jnp.float32), (None, i32), (P, i32), (P, i32), (P, i32),
@@ -383,6 +386,7 @@ def adaptive_policy_paged_attention_kernel(
                              renorm_at=renorm_at)
     i32, f32 = jnp.int32, jnp.float32
     return _fused_call(
+        "adaptive_policy_paged_attention",
         kern, q, k_pages, v_pages, new_k, new_v, pos,
         [f, r, page_start, clock, open_slot, blocks, tag, stamp, refbits,
          p_plane, ctr],

@@ -38,15 +38,23 @@ N requests into a directory TensorBoard/perfetto can open.
 ``PHASES`` is the module-global ``SpanSet`` for phase timers that have
 no engine to live on (the sweep path); engine-scoped phases stay on
 ``ServeEngine.spans``.
+
+``op_scopes`` joins a device trace to the program's layers: a profiler
+trace names each device op by its instruction name in the compiled module
+and carries no scope, while the module's optimized HLO text carries each
+op's ``op_name`` path, where ``jax.named_scope`` components appear.
+``Sentinel.op_scopes()`` maps the ops of the last traced program to the
+scopes the sentinel was given.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import re
 import time
 import weakref
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, Optional, Sequence
 
 import jax
 import numpy as np
@@ -58,6 +66,7 @@ __all__ = [
     "instrument",
     "compile_metrics",
     "count_eqns",
+    "op_scopes",
     "TraceCapture",
     "PHASES",
 ]
@@ -87,6 +96,110 @@ def count_eqns(jaxpr) -> int:
                 if hasattr(item, "eqns") or hasattr(item, "jaxpr"):
                     n += count_eqns(item)
     return n
+
+
+#: scope of a fusion whose fused instructions lie under two or more scopes
+MIXED = "mixed"
+
+_HEADER = re.compile(r"^(ENTRY )?%(\S+) .*\{$")
+_INSTR = re.compile(r"^\s+(?:ROOT )?%(\S+) = ")
+_OPCODE = re.compile(r"\s([a-z][a-z0-9-]*)\(")
+_OPERAND = re.compile(r"%([^\s,()]+)")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_CALLS = re.compile(r"\bcalls=%([^\s,]+)")
+# computations whose instructions run as ops of their own: loop bodies and
+# conditions, conditional branches
+_CONTROL = re.compile(
+    r"\b(?:condition|body|true_computation|false_computation)=%([^\s,]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+# fused instructions that do no work of their own scope: the compiler
+# shares one constant or broadcast among every scope that uses it
+_SHARED = frozenset({"parameter", "constant", "broadcast", "iota"})
+
+
+def op_scopes(hlo_text: str, scopes: Sequence[str]) -> Dict[str, str]:
+    """Map each op of a compiled module that runs as an op of its own (the
+    instructions of the entry computation and of every loop body,
+    condition and branch they reach) to the scope of
+    ``scopes`` it belongs to, ``MIXED`` or ``""``.
+
+    An instruction's scope is the innermost component of its ``op_name``
+    that is one of ``scopes``.  An op takes, in this order:
+
+    1. for a fusion (or async op), the scopes of the instructions of the
+       computation it calls, nested fusions included, parameters, constants
+       and broadcasts left out: the one scope they lie under, ``MIXED``
+       where they span two or more;
+    2. its own scope (a gather the compiler expands keeps its path on the
+       fusion alone);
+    3. where the compiler left it no scope (a scatter it rewrites), the one
+       scope of the instructions whose results it reads, where they agree;
+    4. ``""``.
+
+    ``hlo_text`` is a module's HLO text with metadata, as
+    ``Compiled.as_text()`` prints it."""
+    scopes = frozenset(scopes)
+    comps: Dict[str, list] = {}
+    entry, body = None, None
+    for line in hlo_text.splitlines():
+        m = _HEADER.match(line)
+        if m:
+            body = comps.setdefault(m.group(2), [])
+            entry = m.group(2) if m.group(1) else entry
+        elif line.startswith("}"):
+            body = None
+        elif body is not None and (m := _INSTR.match(line)):
+            body.append((m.group(1), line))
+
+    def own(line: str) -> str:
+        m = _OP_NAME.search(line)
+        found = [c for c in m.group(1).split("/") if c in scopes] if m else []
+        return found[-1] if found else ""
+
+    fused: Dict[str, frozenset] = {}
+
+    def work(line: str) -> frozenset:
+        """Scopes of the work a fusion does (step 1)."""
+        m = _CALLS.search(line)
+        if not m:
+            return frozenset()
+        comp = m.group(1)
+        if comp not in fused:
+            found = set()
+            for _, inner in comps.get(comp, ()):
+                op = _OPCODE.search(inner.split(" = ", 1)[1])
+                if _CALLS.search(inner):
+                    found |= work(inner)
+                elif not op or op.group(1) not in _SHARED:
+                    found.add(own(inner))
+            fused[comp] = frozenset(found - {""})
+        return fused[comp]
+
+    out: Dict[str, str] = {}
+    todo, seen = [entry] if entry else [], set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen:
+            continue
+        seen.add(comp)
+        local: Dict[str, str] = {}
+        for name, line in comps.get(comp, ()):
+            found = work(line)
+            if len(found) > 1:
+                local[name] = MIXED
+            elif found:
+                local[name] = next(iter(found))
+            elif scope := own(line):
+                local[name] = scope
+            else:
+                read = {local[o] for o in _OPERAND.findall(
+                    line.split(" = ", 1)[1]) if o in local} - {""}
+                local[name] = read.pop() if len(read) == 1 else ""
+            todo += _CONTROL.findall(line)
+            for group in _BRANCHES.findall(line):
+                todo += [c.strip().lstrip("%") for c in group.split(",")]
+        out.update(local)
+    return out
 
 
 def _abstract(x: Any) -> Any:
@@ -119,10 +232,14 @@ class Sentinel:
       (trace + lowering + compile, as the caller experienced it);
     * ``eqns`` — jaxpr equation count of the most recent trace (lazy
       audit; ``-1`` when the abstract re-trace failed).
+
+    ``scopes`` names the ``jax.named_scope``s inside the program that
+    ``op_scopes()`` maps its ops to.
     """
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, scopes: Sequence[str] = ()):
         self.name = str(name)
+        self.scopes = tuple(scopes)
         self.calls = 0
         self.traces = 0
         self.last_trace_s = 0.0
@@ -130,6 +247,8 @@ class Sentinel:
         self._jits: list = []
         self._pending: Optional[tuple] = None
         self._last: Optional[tuple] = None
+        self._op_scopes: Dict[str, str] = {}
+        self._op_scopes_of: Optional[tuple] = None
         _ALL.add(self)
 
     @property
@@ -196,6 +315,16 @@ class Sentinel:
         jfn, a, k = self._last
         return jfn.lower(*a, **k).compile().as_text()
 
+    def op_scopes(self) -> Dict[str, str]:
+        """``op_scopes`` of the most recently traced program over this
+        sentinel's ``scopes``: op name (as a device trace names it) ->
+        scope, ``MIXED`` or ``""``.  Computed from ``compiled_text()``
+        once per traced program; nothing runs on the hot path."""
+        if self._op_scopes_of is not self._last:
+            self._op_scopes = op_scopes(self.compiled_text(), self.scopes)
+            self._op_scopes_of = self._last
+        return self._op_scopes
+
     def metrics(self) -> Dict[str, Any]:
         """This sentinel's gauge dict (resolves any pending audit)."""
         self.audit()
@@ -209,16 +338,19 @@ class Sentinel:
 
 
 def instrument(name: str, fun: Optional[Callable] = None, *,
-               audit_eqns: bool = True, **jit_kwargs):
+               audit_eqns: bool = True, scopes: Sequence[str] = (),
+               **jit_kwargs):
     """Wrap ``fun`` in a fresh named sentinel: ``instrument("decode",
     fn, donate_argnums=(1,))`` replaces ``jax.jit(fn, ...)`` and mounts
     the compile counters under ``compile/decode/...``.  Usable as a
     decorator via ``functools.partial(instrument, "name", **jit_kwargs)``
-    in place of ``functools.partial(jax.jit, **jit_kwargs)``."""
+    in place of ``functools.partial(jax.jit, **jit_kwargs)``.  ``scopes``
+    is the sentinel's (``Sentinel.op_scopes``)."""
     if fun is None:
         return functools.partial(instrument, name, audit_eqns=audit_eqns,
-                                 **jit_kwargs)
-    return Sentinel(name).wrap(fun, audit_eqns=audit_eqns, **jit_kwargs)
+                                 scopes=scopes, **jit_kwargs)
+    return Sentinel(name, scopes).wrap(fun, audit_eqns=audit_eqns,
+                                       **jit_kwargs)
 
 
 def compile_metrics() -> Dict[str, Dict[str, Any]]:

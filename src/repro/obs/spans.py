@@ -10,7 +10,11 @@ provider so the totals ride the same flat snapshot as the cache counters
 
 These are HOST timings around device work — they include dispatch and
 any sync the wrapped section performs, which is the serving-relevant
-number.  Spans never appear inside jitted code.
+number.  Spans never appear inside jitted code.  Each span is also a
+``jax.profiler.TraceAnnotation`` of its name, so a profiler capture
+(``ServeEngine(profile_dir=...)``, the benchmark's ``--trace 1``) shows it
+on the host plane, on the device trace's clock, where device idle gaps can
+be laid to it.
 
 Sync discipline (DESIGN.md §12): jax dispatch is async, so a span around
 a bare jitted call times only ENQUEUE unless something inside it blocks.
@@ -70,24 +74,27 @@ class SpanSet:
         """Time one ``with``-scoped section under ``name``; exceptions
         propagate but the elapsed time is still recorded.  Yields a
         handle whose ``ready(x)`` enrolls device values to block on at
-        close in sync mode (see the module docstring)."""
-        h = _Span(self.sync)
-        t0 = time.perf_counter()
-        try:
-            yield h
-        finally:
-            if h._pending:
-                import jax
+        close in sync mode (see the module docstring).  The section is
+        also a ``jax.profiler.TraceAnnotation`` named ``name``, so a
+        profiler capture shows it on the device trace's clock."""
+        import jax
 
-                jax.block_until_ready(h._pending)
-            dt = time.perf_counter() - t0
-            acc = self._acc.setdefault(name, [0, 0.0, 0.0])
-            acc[0] += 1
-            acc[1] += dt
-            acc[2] = max(acc[2], dt)
-            self._samples.setdefault(
-                name, deque(maxlen=self._max_samples)
-            ).append(dt)
+        h = _Span(self.sync)
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield h
+            finally:
+                if h._pending:
+                    jax.block_until_ready(h._pending)
+                dt = time.perf_counter() - t0
+                acc = self._acc.setdefault(name, [0, 0.0, 0.0])
+                acc[0] += 1
+                acc[1] += dt
+                acc[2] = max(acc[2], dt)
+                self._samples.setdefault(
+                    name, deque(maxlen=self._max_samples)
+                ).append(dt)
 
     @staticmethod
     def _pct(xs: list, q: float) -> float:

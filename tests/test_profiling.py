@@ -211,11 +211,135 @@ def test_spans_sync_mode_blocks_on_ready_values():
         out = sp.ready(jnp.ones((64, 64)) @ jnp.ones((64, 64)))
     assert np.asarray(out)[0, 0] == 64.0
     assert ss.metrics()["decode"]["calls"] == 1
-    # sync=False: ready() is free and no jax import happens at close
+    # sync=False: ready() is free and nothing is blocked on at close
     ss2 = SpanSet(sync=False)
     with ss2.span("decode") as sp:
         sp.ready(jnp.ones(2))
     assert ss2.metrics()["decode"]["calls"] == 1
+
+
+def test_span_is_a_trace_annotation_on_the_host_plane(tmp_path):
+    """A span opens a profiler annotation of its name: a capture shows it on
+    the host plane, on the clock of the device trace."""
+    ss = SpanSet()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with ss.span("span_on_the_profiler_clock"):
+            jnp.ones(8).sum().block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    pd = jax.profiler.ProfileData.from_file(str(path))
+    host = [e.name for plane in pd.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+    assert "span_on_the_profiler_clock" in host
+    assert ss.metrics()["span_on_the_profiler_clock"]["calls"] == 1
+
+
+# ---------------------------------------------------------------------------
+# op-to-scope maps (Sentinel.op_scopes)
+# ---------------------------------------------------------------------------
+
+
+def test_op_scopes_fusion_spanning_two_scopes_is_mixed():
+    """XLA fuses the elementwise chain of two scopes into one loop fusion:
+    the fusion's instructions span both scopes, so it maps to ``mixed``."""
+
+    def f(x, y):
+        with jax.named_scope("core_a"):
+            u = jnp.sin(x) * 2
+        with jax.named_scope("core_b"):
+            return jnp.cos(u) + y
+
+    s = Sentinel("t_mixed", scopes=("core_a", "core_b"))
+    s.wrap(f)(jnp.ones(64), jnp.ones(64))
+    fusions = {k: v for k, v in s.op_scopes().items() if "fusion" in k}
+    assert list(fusions.values()) == [profiling.MIXED]
+
+
+def test_op_scopes_is_computed_once_per_traced_program(monkeypatch):
+    s = Sentinel("t_scopes_cache", scopes=("core_a",))
+    g = s.wrap(lambda x: x * 2)
+    g(jnp.ones(4))
+    texts = []
+    real = s.compiled_text
+    monkeypatch.setattr(s, "compiled_text",
+                        lambda: texts.append(1) or real())
+    first = s.op_scopes()
+    assert s.op_scopes() is first and len(texts) == 1
+    g(jnp.ones(4))  # same shape: no trace, same map
+    assert s.op_scopes() is first and len(texts) == 1
+    g(jnp.ones(5))  # a new program: a new map
+    s.op_scopes()
+    assert len(texts) == 2
+
+
+# a loop whose body holds every rule of ``op_scopes``: a constant broadcast
+# the compiler shares across scopes, a fusion of two scopes' work, a gather
+# expansion that keeps its path on the fusion alone, an op with no metadata
+# that reads one scope's result, and glue that reads two
+_HLO = """\
+HloModule m, is_scheduled=true
+
+%fused_shared (p0: s32[8]) -> s32[8] {
+  %p0 = s32[8]{0} parameter(0)
+  %c = s32[] constant(1)
+  %b = s32[8]{0} broadcast(%c), dimensions={}, metadata={op_name="jit(f)/while/body/sweep_arc/broadcast_in_dim"}
+  ROOT %add.1 = s32[8]{0} add(%p0, %b), metadata={op_name="jit(f)/while/body/sweep_car/add"}
+}
+
+%fused_two (p0.1: s32[8]) -> s32[8] {
+  %p0.1 = s32[8]{0} parameter(0)
+  %neg.1 = s32[8]{0} negate(%p0.1), metadata={op_name="jit(f)/while/body/sweep_flat/neg"}
+  ROOT %abs.1 = s32[8]{0} abs(%neg.1), metadata={op_name="jit(f)/while/body/sweep_arc/abs"}
+}
+
+%fused_gather (p0.2: s32[8]) -> s32[8] {
+  %p0.2 = s32[8]{0} parameter(0)
+  ROOT %gather.1 = s32[8]{0} negate(%p0.2), metadata={op_name="gather"}
+}
+
+%body (arg: (s32[], s32[8])) -> (s32[], s32[8]) {
+  %arg = (s32[], s32[8]{0}) parameter(0)
+  %i = s32[] get-tuple-element(%arg), index=0
+  %x = s32[8]{0} get-tuple-element(%arg), index=1
+  %shared_fusion = s32[8]{0} fusion(%x), kind=kLoop, calls=%fused_shared
+  %two_fusion = s32[8]{0} fusion(%shared_fusion), kind=kLoop, calls=%fused_two
+  %gather_fusion = s32[8]{0} fusion(%x), kind=kCustom, calls=%fused_gather, metadata={op_name="jit(f)/while/body/sweep_flat/jit(take_along_axis)/gather"}
+  %copy.3 = s32[8]{0} copy(%gather_fusion)
+  %glue = s32[8]{0} add(%copy.3, %shared_fusion)
+  %one = s32[] constant(1)
+  %next = s32[] add(%i, %one)
+  ROOT %tuple.1 = (s32[], s32[8]{0}) tuple(%next, %glue)
+}
+
+%cond (arg.1: (s32[], s32[8])) -> pred[] {
+  %arg.1 = (s32[], s32[8]{0}) parameter(0)
+  %j = s32[] get-tuple-element(%arg.1), index=0
+  %n = s32[] constant(4)
+  ROOT %lt = pred[] compare(%j, %n), direction=LT
+}
+
+ENTRY %main (a: s32[8]) -> s32[8] {
+  %a = s32[8]{0} parameter(0)
+  %zero = s32[] constant(0)
+  %t = (s32[], s32[8]{0}) tuple(%zero, %a)
+  %while.1 = (s32[], s32[8]{0}) while(%t), condition=%cond, body=%body
+  ROOT %out = s32[8]{0} get-tuple-element(%while.1), index=1
+}
+"""
+
+
+def test_op_scopes_rules_on_hand_written_hlo():
+    m = profiling.op_scopes(_HLO, ("sweep_flat", "sweep_arc", "sweep_car"))
+    assert m["shared_fusion"] == "sweep_car"  # the shared broadcast left out
+    assert m["two_fusion"] == profiling.MIXED
+    assert m["gather_fusion"] == "sweep_flat"  # the fusion's own path
+    assert m["copy.3"] == "sweep_flat"  # reads one scope's result
+    assert m["glue"] == ""  # reads two
+    assert m["next"] == m["lt"] == m["while.1"] == ""
+    # only ops that run: no fused instruction is in the map
+    assert not {"add.1", "neg.1", "gather.1", "b"} & m.keys()
 
 
 # ---------------------------------------------------------------------------

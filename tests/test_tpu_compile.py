@@ -13,6 +13,7 @@ file.  The file skips where no v5e topology can be described.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,6 +49,14 @@ def _compile(one_chip, fn, *shapes):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernels(text):
+    """Base names of the Pallas kernels (``tpu_custom_call`` ops) in a
+    compiled program: the ``name=`` of each ``pallas_call``, which a
+    device trace shows as the op's name."""
+    return {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)}
+
+
 I32, BF16, F32 = jnp.int32, jnp.bfloat16, jnp.float32
 _ATTN = [((B, KVH, G, HD), BF16), ((B, P, PAGE, KVH, HD), BF16),
          ((B, P, PAGE, KVH, HD), BF16), ((B, KVH, HD), BF16),
@@ -63,7 +72,7 @@ def test_awrp_select_rows_compiles_at_sweep_grid_size(one_chip):
         lambda f, r, c, v: ops.awrp_select_rows(f, r, c, v, interpret=False),
         ((4096, 256), I32), ((4096, 256), I32), ((4096,), I32),
         ((4096, 256), I32))
-    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"awrp_select_rows"}
 
 
 def test_fused_flat_policy_attention_compiles(one_chip):
@@ -72,7 +81,7 @@ def test_fused_flat_policy_attention_compiles(one_chip):
         lambda *a: ops.policy_paged_attention(*a, policy="awrp",
                                               interpret=False),
         *_ATTN)
-    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"policy_paged_attention"}
 
 
 def test_fused_adaptive_policy_attention_compiles(one_chip):
@@ -83,4 +92,4 @@ def test_fused_adaptive_policy_attention_compiles(one_chip):
             *a, kind="arc", renorm_at=2**31 - 1024, interpret=False),
         *_ATTN, ((B, L), I32), ((B, L), I32), ((B, L), I32), ((B, L), I32),
         ((B,), F32), ((B,), I32))
-    assert "tpu_custom_call" in text
+    assert _kernels(text) == {"adaptive_policy_paged_attention"}
