@@ -229,11 +229,12 @@ class FlatState(NamedTuple):
     ``(rows, num_sets, ways)`` planes with a ``(rows, num_sets)`` clock;
     single-set cores (``num_sets == 1`` — the sweep engine's layout and
     every serving caller) DROP the sets axis: ``(rows, ways)`` planes,
-    ``(rows,)`` clock.  The squeeze is not cosmetic — scatter updates that
-    round-trip a reshape defeat XLA's in-place scan-carry optimization and
-    cost ~20% of the engine's step budget on CPU.  ``blocks == -1`` marks
-    an empty lane; dead lanes (capacity padding in a mixed-ways batch) are
-    identified by the core's mask, never a sentinel."""
+    ``(rows,)`` clock.  Single-set planes are updated by lane selects
+    (``_row_step``): one elementwise pass rewrites each row's chosen lane,
+    with no gather or scatter.  Set-associative planes gather each access's
+    set row, update it the same way and write it back whole.
+    ``blocks == -1`` marks an empty lane; dead lanes (capacity padding in a
+    mixed-ways batch) are identified by the core's mask, never a sentinel."""
 
     blocks: jax.Array  # (B[, S], W) int32, -1 = empty
     f: jax.Array  # (B[, S], W) int32 frequency counters
@@ -311,7 +312,11 @@ def _row_step(
     masks: _GridMasks,
     use_kernel: bool,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Shared per-access decision logic -> (slot, is_hit, new_f, new_r)."""
+    """One access per row -> (blocks', f', r', is_hit), the updated (B, W)
+    planes.  Each row's chosen lane (the hit lane, else the victim) is
+    rewritten by an elementwise select on a one-hot lane mask: the new value
+    is a function of that lane's own old value, so the select reads and
+    writes the lane in one pass, with no per-row gather or scatter."""
     W = row_blocks.shape[-1]
     iota = masks.iota
 
@@ -322,12 +327,14 @@ def _row_step(
 
     victim = _flat_victim(row_f, row_r, clk, masks, use_kernel)
     slot = jnp.where(is_hit, hit_k, victim)
-    old_f = jnp.take_along_axis(row_f, slot[:, None], -1)[:, 0]
-    old_r = jnp.take_along_axis(row_r, slot[:, None], -1)[:, 0]
-    new_f = jnp.where(is_hit, old_f + 1, 1).astype(jnp.int32)
+    sel = iota == slot[:, None]  # (B, W): exactly one lane per row
+    hit = is_hit[:, None]
+    new_blocks = jnp.where(sel, block[:, None], row_blocks)
+    new_f = jnp.where(sel, jnp.where(hit, row_f + 1, 1), row_f)
     # FIFO keeps its insertion clock in R: freeze R on hits for FIFO rows
-    new_r = jnp.where(is_hit & masks.fifo_row, old_r, clk).astype(jnp.int32)
-    return slot, is_hit, new_f, new_r
+    keep_r = (is_hit & masks.fifo_row)[:, None]
+    new_r = jnp.where(sel, jnp.where(keep_r, row_r, clk[:, None]), row_r)
+    return new_blocks, new_f, new_r, is_hit
 
 
 # ---------------------------------------------------------------------------
@@ -939,24 +946,19 @@ class FlatCore(_Accounting):
         ids = jnp.asarray(ids, dtype=jnp.int32)
         if masks is None:
             masks = self._masks()
-        bidx = jnp.arange(self.rows)
         if self.num_sets == 1:
             # single-set layout: (B, W) planes, no sets axis (see FlatState)
             clk = state.clock + 1
-            slot, is_hit, new_f, new_r = _row_step(
+            blocks, f, r, is_hit = _row_step(
                 state.blocks, state.f, state.r, clk, ids, masks,
                 self.use_kernel,
             )
-            new_state = FlatState(
-                blocks=state.blocks.at[bidx, slot].set(ids),
-                f=state.f.at[bidx, slot].set(new_f),
-                r=state.r.at[bidx, slot].set(new_r),
-                clock=clk,
-            )
+            new_state = FlatState(blocks=blocks, f=f, r=r, clock=clk)
         else:
+            bidx = jnp.arange(self.rows)
             sid = ids % self.num_sets
             clk = state.clock[bidx, sid] + 1
-            slot, is_hit, new_f, new_r = _row_step(
+            blocks, f, r, is_hit = _row_step(
                 state.blocks[bidx, sid],
                 state.f[bidx, sid],
                 state.r[bidx, sid],
@@ -966,9 +968,9 @@ class FlatCore(_Accounting):
                 self.use_kernel,
             )
             new_state = FlatState(
-                blocks=state.blocks.at[bidx, sid, slot].set(ids),
-                f=state.f.at[bidx, sid, slot].set(new_f),
-                r=state.r.at[bidx, sid, slot].set(new_r),
+                blocks=state.blocks.at[bidx, sid].set(blocks),
+                f=state.f.at[bidx, sid].set(f),
+                r=state.r.at[bidx, sid].set(r),
                 clock=state.clock.at[bidx, sid].set(clk),
             )
         if active is not None:

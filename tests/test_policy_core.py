@@ -122,6 +122,78 @@ def test_incremental_set_associative_matches_host(policy):
     assert (hits == host_hits_rows(policy, streams, 12, num_sets=4)).all()
 
 
+def _primitives(jaxpr):
+    """Names of every primitive in a (closed) jaxpr, nested jaxprs in eqn
+    params (jit, scan, cond bodies) included."""
+    jaxpr = getattr(jaxpr, "jaxpr", jaxpr)
+    names = set()
+    for eqn in jaxpr.eqns:
+        names.add(eqn.primitive.name)
+        for v in eqn.params.values():
+            for item in (v if isinstance(v, (tuple, list)) else (v,)):
+                if hasattr(item, "eqns") or hasattr(item, "jaxpr"):
+                    names |= _primitives(item)
+    return names
+
+
+#: a mixed single-set batch: every flat policy at two capacities, padded to
+#: eight lanes, so each row has dead lanes after its last live one
+_MIXED_POLICIES = JAX_POLICIES * 2
+_MIXED_WAYS = (3,) * len(JAX_POLICIES) + (5,) * len(JAX_POLICIES)
+
+
+def _mixed_core(lanes=8):
+    return FlatCore(
+        pids=tuple(POLICY_IDS[p] for p in _MIXED_POLICIES),
+        ways=_MIXED_WAYS, lanes=lanes,
+    )
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["all", "active"])
+def test_single_set_update_has_no_gather_or_scatter(masked):
+    """A single-set access reads and writes each row's chosen lane with
+    lane selects: its jaxpr holds no gather or scatter, at any depth."""
+    import jax
+
+    core = _mixed_core()
+    active = np.arange(core.rows) % 3 != 0 if masked else None
+    ids = np.arange(core.rows, dtype=np.int32)
+    jaxpr = jax.make_jaxpr(
+        lambda st, i: core.on_access(st, i, active=active)
+    )(core.init(), ids)
+    prims = _primitives(jaxpr)
+    assert "select_n" in prims
+    assert not {p for p in prims if p.startswith(("gather", "scatter"))}
+
+
+@pytest.mark.parametrize("lanes", [None, 8], ids=["unpadded", "padded"])
+def test_lane_select_edge_cases_match_host_oracles(lanes):
+    """Each row fills empty lanes (also right after a hit), hits its last
+    live lane with dead lanes after it, takes a hit that a FIFO row must not
+    restamp, then misses and re-reads the block FIFO evicts: access by
+    access equal to the host oracles, dead lanes never written."""
+    rng = np.random.RandomState(17)
+    streams = []
+    for w in _MIXED_WAYS:
+        head = [1, 1, 2, 2] + list(range(3, w + 1)) + [w, 1, w + 1, 1, w + 2]
+        tail = rng.randint(1, 2 * w, size=120 - len(head))
+        streams.append(np.concatenate([head, tail]))
+    streams = np.asarray(streams, dtype=np.int32)
+    core = _mixed_core(lanes)
+    state, hits = drive(core, core.init(), streams)
+    for row, (policy, w) in enumerate(zip(_MIXED_POLICIES, _MIXED_WAYS)):
+        ref = host_hits_rows(policy, streams[row:row + 1], w)[0]
+        diverged = np.flatnonzero(hits[row] != ref)
+        assert diverged.size == 0, (policy, w, diverged[:1])
+        for plane, empty in ((state.blocks, -1), (state.f, 0), (state.r, 0)):
+            assert (np.asarray(plane)[row, w:] == empty).all(), (policy, w)
+    # the FIFO rows miss on block 1 after evicting it: its hit kept R
+    for row, policy in enumerate(_MIXED_POLICIES):
+        w = _MIXED_WAYS[row]
+        if policy == "fifo":
+            assert not hits[row, w + 5] and hits[row, w + 3]
+
+
 def test_core_equals_batched_engine():
     """The engine IS a scan over on_access: incremental driving reproduces
     simulate_trace_batched bit-for-bit for every device policy."""
