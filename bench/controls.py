@@ -1,6 +1,6 @@
 """Read the numbers a cell's check compares, for the program and for its
-control, on many seeds in one process: the readings the check's limits
-are set from.
+control, on many seeds, one process a seed: the readings the check's
+limits are set from.
 
     python3 bench/controls.py --workload serve.longctx --seeds 11 12 13
     python3 bench/controls.py --workload sweep.table1 --seeds 11 12 13
@@ -15,17 +15,20 @@ sample (the driver's ``control``):
 * serve: ``control_gap``, at each served position the gap under the
   float32 reference of the token the float8 reference puts first.
 
-A measuring machine is needed, as for ``bench/run.py``; ``--rehearse``
-runs the cell's tiny sizes on the CPU.
+Given several seeds it runs itself once for each and stays off JAX,
+whose chip the child needs: a dropped ``ServeEngine`` is never freed,
+so a dozen engines in one process would not fit the chip.  A measuring
+machine is needed, as for ``bench/run.py``; ``--rehearse`` runs the
+cell's tiny sizes on the CPU.
 """
 
 from __future__ import annotations
 
 import argparse
-import gc
 import json
 import os
 import pathlib
+import subprocess
 import sys
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -38,6 +41,13 @@ def main(argv=None) -> int:
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
+    if len(args.seeds) > 1:
+        for seed in args.seeds:
+            cmd = [sys.executable, __file__, "--workload", args.workload,
+                   "--seeds", str(seed)] + ["--rehearse"] * args.rehearse
+            if subprocess.run(cmd).returncode:
+                return 1
+        return 0
     if args.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
     from bench import harness, traffic
@@ -48,18 +58,15 @@ def main(argv=None) -> int:
     mix = traffic.load_mix(cell["traffic"], rehearsal=args.rehearse)
     harness.device_info(cell["chips"], args.rehearse)
     enable_compile_cache()
-    drv = harness.driver(cfg)
-    for seed in args.seeds:
-        run = drv.Run(cfg, mix, seed, args.rehearse)
-        run.setup(warm=False)
-        run.window(0.0, 1)
-        run.release()
-        out = {name: value for name, value, _ in run.check()}
-        out.update(run.control())
-        print(json.dumps({"workload": args.workload, "seed": seed, **out}),
-              flush=True)
-        del run
-        gc.collect()
+    seed, = args.seeds
+    run = harness.driver(cfg).Run(cfg, mix, seed, args.rehearse)
+    run.setup(warm=False)
+    run.window(0.0, 1)
+    run.release()
+    out = {name: value for name, value, _ in run.check()}
+    out.update(run.control())
+    print(json.dumps({"workload": args.workload, "seed": seed, **out}),
+          flush=True)
     return 0
 
 

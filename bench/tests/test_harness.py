@@ -1,6 +1,7 @@
 """The harness on the CPU: discovery by name, the refusal of a device that
 is not a TPU, every cell's rehearsal, and the faults its check catches."""
 
+import collections
 import json
 import os
 import shutil
@@ -11,20 +12,19 @@ import types
 import jax
 import pytest
 
-from bench import harness
+from bench import harness, traffic
 
 ROOT = harness.ROOT
 CELLS = [c["name"] for c in harness.load_benchmark()["workloads"]]
 
-#: serving cells whose files are under bench/; a checkout whose
-#: BENCHMARK.json lacks one gets its entries in a temporary copy
+#: serving cells whose files are under bench/ but that BENCHMARK.json
+#: lacks; a run of one gets its entries in a temporary copy
 SERVE = {
     "config": {"name": "smollm-360m-pagedkv", "source": "x", "reduced": [],
                "file": "bench/configs/smollm-360m-pagedkv.json", "why": "x"},
-    "cells": {name: {"name": name, "config": "smollm-360m-pagedkv",
-                     "traffic": traffic, "chips": 1, "why": "x"}
-              for name, traffic in (("serve.longctx", "longctx"),
-                                    ("serve.longprompt", "longprompt"))},
+    "cells": {"serve.longprompt": {
+        "name": "serve.longprompt", "config": "smollm-360m-pagedkv",
+        "traffic": "longprompt", "chips": 1, "why": "x"}},
 }
 
 
@@ -90,6 +90,55 @@ def test_every_cell_rehearses_correct_and_prints_no_metric(workload,
     assert out["attempted"] >= 1 and out["failed"] == 0
     assert list(out)[-1] == "checks"
     assert record["compiles_in_window"] == 0
+
+
+def _cells_with_setup_alone(bench):
+    """Cells whose only end-to-end metric is ``setup_s``."""
+    return [c["name"] for c in bench["workloads"]
+            if {m["name"] for m in harness.cell_metrics(
+                bench, c["name"], "end_to_end")} <= {"setup_s"}]
+
+
+def test_every_cell_reports_an_end_to_end_metric_besides_setup():
+    assert _cells_with_setup_alone(harness.load_benchmark()) == []
+
+
+def test_a_serving_cell_without_serve_tok_s_reports_setup_alone():
+    bench = harness.load_benchmark()
+    for m in bench["end_to_end"]:
+        if m["name"] == "serve_tok_s":
+            m["workloads"].remove("serve.longctx")
+    assert _cells_with_setup_alone(bench) == ["serve.longctx"]
+
+
+@pytest.mark.parametrize("workload", CELLS)
+def test_the_driver_computes_every_end_to_end_metric_of_its_cell(workload):
+    """The names a cell's end-to-end metrics declare, less ``setup_s``,
+    which the harness takes itself, are what its driver's ``end_to_end``
+    returns, here on a window record whose every number is 1."""
+    bench = harness.load_benchmark()
+    cell, entry = harness.find_cell(bench, workload)
+    cfg = harness.load_config(entry)
+    run = harness.driver(cfg).Run(cfg, traffic.load_mix(cell["traffic"]),
+                                  1, False)
+    got = run.end_to_end(collections.defaultdict(lambda: 1))
+    want = {m["name"] for m in harness.cell_metrics(bench, workload,
+                                                    "end_to_end")}
+    assert want - {"setup_s"} <= set(got)
+    assert all(v > 0 for v in got.values())
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in
+                                    harness.load_benchmark()["per_layer"]])
+def test_every_per_layer_metric_moves_an_end_to_end_metric_of_its_cells(
+        metric):
+    bench = harness.load_benchmark()
+    m = {m["name"]: m for m in bench["per_layer"]}[metric]
+    assert m["moves"] in {e["name"] for e in bench["end_to_end"]}
+    for workload in m.get("workloads", CELLS):
+        assert m["moves"] in {e["name"] for e in harness.cell_metrics(
+            bench, workload, "end_to_end")}
+    assert (ROOT / "bench" / "metrics" / f"{metric}.py").is_file()
 
 
 def _hashes(root):
