@@ -123,16 +123,13 @@ def awrp_victim(
     return awrp_victim_rows(f, r, clock, valid & ~pinned)  # (B,)
 
 
-def insert_token(
-    pool: PagedPool,
-    new_k: jax.Array,  # (B, kvd)
-    new_v: jax.Array,  # (B, kvd)
-    pos: jax.Array,  # scalar int32 — token index being written
-    page_size: int,
-    policy: str = "awrp",
-) -> PagedPool:
-    """Write one token row; on page-boundary allocate (evicting by ``policy``
-    when the pool is full).  Branch-free — runs under jit/scan."""
+def _allocate(pool, pos: jax.Array, page_size: int, policy: str):
+    """The page-boundary step of a token write, shared by every classic
+    pool: ``(within, need_alloc, slot, f, r, page_start)`` — the row in
+    the page, whether ``pos`` opens a page, the slot written (on a
+    boundary the first free slot, else ``policy``'s victim, never the open
+    page), and the metadata with the allocated page reset (paper insert
+    rule: F=1, R=N)."""
     from repro.core.kv_policy import page_victim
 
     B, P = pool.f.shape
@@ -150,7 +147,6 @@ def insert_token(
     slot = jnp.where(need_alloc, alloc_slot, pool.open_slot)  # (B,)
 
     bidx = jnp.arange(B)
-    # on allocation: reset the page (paper insert rule: F=1, R=N)
     f = pool.f.at[bidx, slot].set(
         jnp.where(need_alloc, 1, pool.f[bidx, slot])
     )
@@ -160,6 +156,23 @@ def insert_token(
     page_start = pool.page_start.at[bidx, slot].set(
         jnp.where(need_alloc, pos, pool.page_start[bidx, slot])
     )
+    return within, need_alloc, slot, f, r, page_start
+
+
+def insert_token(
+    pool: PagedPool,
+    new_k: jax.Array,  # (B, kvd)
+    new_v: jax.Array,  # (B, kvd)
+    pos: jax.Array,  # scalar int32 — token index being written
+    page_size: int,
+    policy: str = "awrp",
+) -> PagedPool:
+    """Write one token row; on page-boundary allocate (evicting by ``policy``
+    when the pool is full).  Branch-free — runs under jit/scan."""
+    B = pool.f.shape[0]
+    within, need_alloc, slot, f, r, page_start = _allocate(
+        pool, pos, page_size, policy)
+    bidx = jnp.arange(B)
     zero_row = jnp.zeros_like(pool.k[:, 0])  # (B, page, kvd)
     k = pool.k.at[bidx, slot].set(
         jnp.where(need_alloc[..., None, None] if need_alloc.ndim else need_alloc,
@@ -173,6 +186,57 @@ def insert_token(
     v = v.at[bidx, slot, within].set(new_v)
     open_slot = jnp.where(need_alloc, slot, pool.open_slot).astype(jnp.int32)
     return PagedPool(k, v, f, r, page_start, pool.clock, open_slot)
+
+
+class LatentPagedPool(NamedTuple):
+    """A bounded pool whose page holds one latent plane: the row multi-head
+    latent attention caches per token (``[c | k_pe]``, shared by every
+    head) in place of per-head K and V.  The AWRP metadata and every
+    function on it (``kv_positions``, ``score_update``, ``step_counts``)
+    are ``PagedPool``'s."""
+
+    c: jax.Array  # (B, P, page, width)
+    f: jax.Array  # (B, P) int32
+    r: jax.Array  # (B, P) int32
+    page_start: jax.Array  # (B, P) int32; -1 free
+    clock: jax.Array  # (B,) int32
+    open_slot: jax.Array  # (B,) int32
+
+
+def latent_pool(batch: int, pages: int, page_size: int, width: int, dtype,
+                *, abstract: bool = False) -> LatentPagedPool:
+    """An empty latent pool (every page free), or its ``ShapeDtypeStruct``
+    skeleton with ``abstract``."""
+    make = ((lambda s, dt, fill: jax.ShapeDtypeStruct(s, dt)) if abstract
+            else (lambda s, dt, fill: jnp.full(s, fill, dt)))
+    return LatentPagedPool(
+        c=make((batch, pages, page_size, width), dtype, 0),
+        f=make((batch, pages), jnp.int32, 0),
+        r=make((batch, pages), jnp.int32, 0),
+        page_start=make((batch, pages), jnp.int32, -1),
+        clock=make((batch,), jnp.int32, 0),
+        open_slot=make((batch,), jnp.int32, 0),
+    )
+
+
+def insert_latent(
+    pool: LatentPagedPool,
+    row: jax.Array,  # (B, width) the new token's latent row
+    pos: jax.Array,  # scalar int32
+    page_size: int,
+    policy: str = "awrp",
+) -> LatentPagedPool:
+    """``insert_token`` for a latent pool: the same allocation and
+    eviction (``_allocate``), one plane written."""
+    B = pool.f.shape[0]
+    within, need_alloc, slot, f, r, page_start = _allocate(
+        pool, pos, page_size, policy)
+    bidx = jnp.arange(B)
+    c = pool.c.at[bidx, slot].set(
+        jnp.where(need_alloc, jnp.zeros_like(pool.c[:, 0]), pool.c[bidx, slot]))
+    c = c.at[bidx, slot, within].set(row)
+    open_slot = jnp.where(need_alloc, slot, pool.open_slot).astype(jnp.int32)
+    return LatentPagedPool(c, f, r, page_start, pool.clock, open_slot)
 
 
 def kv_positions(pool: PagedPool, pos: jax.Array, page_size: int) -> jax.Array:
@@ -217,7 +281,8 @@ def score_update(
 def _pools(caches):
     """Every ``PagedPool`` in a cache tree (the ``pool`` of an adaptive one),
     in traversal order."""
-    is_pool = lambda x: isinstance(x, (PagedPool, AdaptivePagedPool))  # noqa: E731
+    is_pool = lambda x: isinstance(  # noqa: E731
+        x, (PagedPool, LatentPagedPool, AdaptivePagedPool))
     return [x.pool if isinstance(x, AdaptivePagedPool) else x
             for x in jax.tree.leaves(caches, is_leaf=is_pool) if is_pool(x)]
 

@@ -12,6 +12,11 @@ Block types (``ModelConfig.pattern`` entries):
   "shared_attn" attention+MLP block whose params are SHARED across every
                 occurrence (Zamba2-style)
   "moe"         full causal self-attention + MoE FFN
+  "mla"         multi-head latent attention (DeepSeek-V2 §2.1) + dense MLP
+  "mla_moe"     multi-head latent attention + MoE FFN (routed + shared
+                experts, DeepSeek-V3 routing)
+``lead`` kinds run once, ahead of the scanned pattern (DeepSeek-V3's
+leading dense layers).
 Encoder-decoder archs use ``enc_layers``/``dec_layers`` instead of pattern.
 """
 
@@ -79,10 +84,28 @@ class ModelConfig:
     n_repeats: int = 0
     tail: Tuple[str, ...] = ()
     sliding_window: int = 0  # window for "local" blocks
+    lead: Tuple[str, ...] = ()  # kinds run once ahead of the pattern scan
+    # MLA (the "mla"/"mla_moe" kinds): per token one cached latent row of
+    # kv_lora_rank + qk_rope_head_dim, shared by every head
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # MoE
     n_experts: int = 0
     top_k: int = 0
     capacity_factor: float = 1.25
+    # softmax | sigmoid (DeepSeek-V3 noaux_tc: a per-expert correction bias
+    # for selection only, the chosen scores divided by their sum)
+    router_scoring: str = "softmax"
+    routed_scaling: float = 1.0  # routed gates' factor after normalising
+    expert_ff: int = 0  # routed expert width (0: d_ff)
+    shared_ff: int = 0  # shared experts' total width (0: none)
+    # expert parallelism: this chip holds experts expert_offset ..
+    # expert_offset + experts_held - 1 of the n_experts it routes over and
+    # computes their share with no token dropped (0: all, capacity dispatch)
+    experts_held: int = 0
+    expert_offset: int = 0
     moe_sharding: str = "tp"  # tp | ep  (hillclimb knob)
     decode_param_mode: str = "fsdp"  # fsdp | tp2d (serving weight layout)
     # SSM (mamba2)
@@ -128,8 +151,8 @@ class ModelConfig:
     def layer_pattern(self) -> Tuple[str, ...]:
         if self.pattern is None:
             unit = ("moe",) if self.n_experts else ("attn",)
-            return unit * self.n_layers
-        return self.pattern * self.n_repeats + self.tail
+            return self.lead + unit * (self.n_layers - len(self.lead))
+        return self.lead + self.pattern * self.n_repeats + self.tail
 
     @property
     def qk_dim(self) -> int:

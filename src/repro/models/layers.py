@@ -108,10 +108,14 @@ def flash_attention(
     window: int = 0,
     q_chunk: int = 512,
     kv_chunk: int = 1024,
+    scale: Optional[float] = None,  # default 1/sqrt(q's head width)
 ) -> jax.Array:
     """Chunked softmax attention with running (m, l, acc). Rectangular
-    schedule + block masking (baseline; see module docstring)."""
+    schedule + block masking (baseline; see module docstring).  ``v`` may
+    be narrower than ``q`` and ``k`` (MLA: qk 192, v 128); the output
+    takes v's width."""
     B, Sq, KVH, G, hd = q.shape
+    hdv = v.shape[-1]
     Skv = k.shape[1]
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Skv)
@@ -126,11 +130,11 @@ def flash_attention(
         v = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
         kv_positions = jnp.pad(kv_positions, (0, pk), constant_values=-1)
     nq, nk = q.shape[1] // q_chunk, k.shape[1] // kv_chunk
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
 
     qs = q.reshape(B, nq, q_chunk, KVH, G, hd).transpose(1, 0, 2, 3, 4, 5)
     ks = k.reshape(B, nk, kv_chunk, KVH, hd).transpose(1, 0, 2, 3, 4)
-    vs = v.reshape(B, nk, kv_chunk, KVH, hd).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(B, nk, kv_chunk, KVH, hdv).transpose(1, 0, 2, 3, 4)
     qps = q_positions.reshape(nq, q_chunk)
     kps = kv_positions.reshape(nk, kv_chunk)
 
@@ -152,15 +156,15 @@ def flash_attention(
         qc = logical_shard(qc, "act_batch", "act_seq", "act_kv_heads", None, None)
         m0 = jnp.full((B, KVH, G, q_chunk), NEG_INF, dtype=jnp.float32)
         l0 = jnp.zeros((B, KVH, G, q_chunk), dtype=jnp.float32)
-        a0 = jnp.zeros((B, KVH, G, q_chunk, hd), dtype=jnp.float32)
+        a0 = jnp.zeros((B, KVH, G, q_chunk, hdv), dtype=jnp.float32)
         (m, l, acc, _, _), _ = jax.lax.scan(
             kv_step, (m0, l0, a0, qc, qpos), (ks, vs, kps)
         )
         out = acc / jnp.maximum(l, 1e-30)[..., None]
-        return None, out.transpose(0, 3, 1, 2, 4)  # (B, cq, KVH, G, hd)
+        return None, out.transpose(0, 3, 1, 2, 4)  # (B, cq, KVH, G, hdv)
 
-    _, outs = jax.lax.scan(q_step, None, (qs, qps))  # (nq, B, cq, KVH, G, hd)
-    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * q_chunk, KVH, G, hd)
+    _, outs = jax.lax.scan(q_step, None, (qs, qps))  # (nq, B, cq, KVH, G, hdv)
+    out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(B, nq * q_chunk, KVH, G, hdv)
     return out[:, :Sq].astype(q.dtype)
 
 
@@ -414,12 +418,200 @@ def decode_project_out(params: Params, out: jax.Array, cfg) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (MLA, DeepSeek-V2 §2.1)
+# ---------------------------------------------------------------------------
+
+#: ``jax.named_scope``s of the serving step's MLA and MoE parts, the
+#: scopes ``obs.profiling.op_scopes`` maps a compiled step's ops to
+SERVE_SCOPES = ("mla_attend", "moe_route", "moe_experts", "moe_shared")
+
+
+def mla_latent(params: Params, x: jax.Array, cfg, positions: jax.Array
+               ) -> jax.Array:
+    """The row MLA caches per token, shared by every head: x (B, S, D) ->
+    (B, S, r + rope) = [RMSNorm(c) | k_pe roped at ``positions`` (B, S)]."""
+    r = cfg.kv_lora_rank
+    ckv = jnp.einsum("bsd,dc->bsc", x, params["w_kva"])
+    c = rmsnorm(ckv[..., :r], params["kv_norm"], cfg.norm_eps)
+    k_pe = rope(ckv[..., None, r:], positions, cfg.rope_theta)[:, :, 0]
+    return jnp.concatenate([c, k_pe.astype(c.dtype)], axis=-1)
+
+
+def _mla_q(params: Params, x: jax.Array, cfg, positions: jax.Array):
+    """Per-head queries (B, S, H, nope) and roped (B, S, H, rope)."""
+    B, S, _ = x.shape
+    dn, dr = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = jnp.einsum("bsd,dh->bsh", x, params["wq"])
+    q = q.reshape(B, S, cfg.n_heads, dn + dr)
+    return q[..., :dn], rope(q[..., dn:], positions, cfg.rope_theta)
+
+
+def _mla_scale(cfg) -> float:
+    return 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+
+
+def mla_attention(params: Params, x: jax.Array, cfg, *,
+                  positions: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """Causal MLA over a whole prompt (train / prefill), naive form: the
+    latent rows expanded to per-head K (nope | shared k_pe) and V.  Returns
+    (out, latent rows (B, S, r + rope)) so prefill can seed the cache."""
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla_attend"):
+        q_nope, q_pe = _mla_q(params, x, cfg, positions[None])
+        lat = mla_latent(params, x, cfg, positions[None])
+        kv = jnp.einsum("bsc,ch->bsh", lat[..., :r], params["w_kvb"])
+        kv = kv.reshape(B, S, H, dn + dv)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(lat[:, :, None, r:], (B, S, H, dr))],
+            axis=-1)
+        q = jnp.concatenate([q_nope, q_pe.astype(q_nope.dtype)], axis=-1)
+        out = flash_attention(
+            q[:, :, :, None], k, kv[..., dn:], q_positions=positions,
+            kv_positions=positions, causal=True, scale=_mla_scale(cfg))
+        out = logical_shard(out.reshape(B, S, H * dv),
+                            "act_batch", "act_seq", "act_feat")
+        proj = jnp.einsum("bsh,hd->bsd", out, params["wo"])
+    return logical_shard(proj, "act_batch", "act_res_seq", "act_embed"), lat
+
+
+def mla_decode_attend(
+    params: Params,
+    x: jax.Array,  # (B, 1, D)
+    cfg,
+    *,
+    position: jax.Array,  # scalar int32
+    cache: jax.Array,  # (B, T, r + rope) latent rows, the new one included
+    kv_positions: jax.Array,  # (B, T) int32, -1 = empty slot
+) -> Tuple[jax.Array, jax.Array]:
+    """One-token MLA in absorbed form over latent rows: the query's nope
+    part is taken into the latent space (q_nope W_UK^T), scores are read
+    against the rows themselves and the output projected from the latent
+    (W_UV), so the cache is never expanded per head.  Returns (out,
+    attn_mass (B, T)) — the mass summed over heads feeds the AWRP scorer."""
+    B = x.shape[0]
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    dn, dv = cfg.qk_nope_head_dim, cfg.v_head_dim
+    with jax.named_scope("mla_attend"):
+        q_nope, q_pe = _mla_q(params, x, cfg,
+                              jnp.full((B, 1), position, jnp.int32))
+        w_kvb = params["w_kvb"].reshape(r, H, dn + dv)
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], w_kvb[..., :dn])
+        qc = jnp.concatenate([q_lat, q_pe[:, 0].astype(q_lat.dtype)], axis=-1)
+        s = jnp.einsum("bhc,btc->bht", qc.astype(cache.dtype), cache,
+                       preferred_element_type=jnp.float32) * _mla_scale(cfg)
+        s = jnp.where((kv_positions >= 0)[:, None], s, NEG_INF)
+        p = jax.nn.softmax(s, axis=-1)
+        o_lat = jnp.einsum("bht,btc->bhc", p.astype(cache.dtype),
+                           cache[..., :r])
+        o = jnp.einsum("bhc,chv->bhv", o_lat, w_kvb[..., dn:])
+        o = logical_shard(o.reshape(B, 1, H * dv),
+                          "act_batch", "act_seq", "act_feat")
+        proj = jnp.einsum("bsh,hd->bsd", o.astype(x.dtype), params["wo"])
+    return proj, p.sum(axis=1)
+
+
+# ---------------------------------------------------------------------------
 # MoE (sort-based dispatch, static capacity — GSPMD-friendly)
 # ---------------------------------------------------------------------------
 
 
-def moe(params: Params, x: jax.Array, cfg) -> jax.Array:
-    """Top-k MoE, sort-based dispatch with PER-SEQUENCE capacity groups.
+def route(params: Params, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
+    """Top-k routing over all ``cfg.n_experts``: (gates (B, S, K) float32,
+    expert ids (B, S, K)).
+
+    ``softmax``: top-k of the softmax, renormalised.  ``sigmoid``
+    (DeepSeek-V3, ``noaux_tc``): float32 logits, sigmoid scores, top-k of
+    score + the correction bias (selection only), the chosen scores
+    divided by their sum, times ``routed_scaling``."""
+    K = cfg.top_k
+    if cfg.router_scoring == "sigmoid":
+        logits = jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32),
+            params["w_router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, expert_idx = jax.lax.top_k(
+            scores + params["router_bias"].astype(jnp.float32), K)
+        gate = jnp.take_along_axis(scores, expert_idx, axis=-1)
+        gate = gate / gate.sum(-1, keepdims=True)
+        return gate * cfg.routed_scaling, expert_idx
+    logits = jnp.einsum("bsd,de->bse", x, params["w_router"]).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, expert_idx = jax.lax.top_k(probs, K)  # (B, S, K)
+    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
+    return gate, expert_idx
+
+
+#: tokens per pass of the held-expert share: a pass sorts and gathers
+#: tokens x top_k rows, so prefill's 16 x 4096 tokens run in 16 passes
+HELD_CHUNK = 4096
+
+
+def held_experts(params: Params, x: jax.Array, gate: jax.Array,
+                 expert_idx: jax.Array, cfg) -> jax.Array:
+    """The held experts' share of the routed output, no token dropped:
+    ``sum_{k in top-k, held} gate_k E_k(x)`` for every token, where this
+    chip holds experts ``cfg.expert_offset ..`` ``+ cfg.experts_held - 1``.
+    Each pass sorts its (token, k) pairs by held expert (pairs for experts
+    held elsewhere last) and runs the three SwiGLU matmuls as grouped
+    matmuls (``jax.lax.ragged_dot``) over the sorted rows."""
+    B, S, D = x.shape
+    K, Eh = cfg.top_k, cfg.experts_held
+    T = B * S
+    local = expert_idx.reshape(T, K) - cfg.expert_offset
+    group = jnp.where((local >= 0) & (local < Eh), local, Eh)  # Eh: elsewhere
+    C = min(T, HELD_CHUNK)
+    pad = (-T) % C
+    xt = jnp.pad(x.reshape(T, D), ((0, pad), (0, 0)))
+    gt = jnp.pad(group, ((0, pad), (0, 0)), constant_values=Eh)
+    wt = jnp.pad(gate.reshape(T, K), ((0, pad), (0, 0)))
+
+    def one_pass(args):
+        xc, gc, wc = args  # (C, D), (C, K), (C, K)
+        flat = gc.reshape(C * K)
+        order = jnp.argsort(flat, stable=True)
+        rows = xc[order // K]
+        sizes = jnp.bincount(flat, length=Eh + 1)[:Eh].astype(jnp.int32)
+        h = (jax.nn.silu(jax.lax.ragged_dot(rows, params["w_gate"], sizes))
+             * jax.lax.ragged_dot(rows, params["w_up"], sizes))
+        y = jax.lax.ragged_dot(h, params["w_down"], sizes)
+        y = jnp.where((flat[order] < Eh)[:, None], y, 0)  # rows held elsewhere
+        y = jnp.zeros_like(y).at[order].set(y).reshape(C, K, D)
+        return jnp.einsum("ckd,ck->cd", y.astype(jnp.float32),
+                          jnp.where(gc < Eh, wc, 0.0))
+
+    n = xt.shape[0] // C
+    if n == 1:
+        out = one_pass((xt, gt, wt))
+    else:
+        out = jax.lax.map(one_pass, (xt.reshape(n, C, D), gt.reshape(n, C, K),
+                                     wt.reshape(n, C, K))).reshape(n * C, D)
+    return out[:T].reshape(B, S, D).astype(x.dtype)
+
+
+def held_counts(expert_idx: jax.Array, cfg) -> jax.Array:
+    """``[held_pairs, experts_hit]`` (int32) of one expert layer's call:
+    the (token, k) pairs routed to experts this chip holds, and how many
+    held experts got at least one."""
+    Eh = cfg.experts_held or cfg.n_experts
+    local = expert_idx.reshape(-1) - cfg.expert_offset
+    held = (local >= 0) & (local < Eh)
+    per = jnp.bincount(jnp.where(held, local, Eh), length=Eh + 1)[:Eh]
+    return jnp.stack([held.sum(dtype=jnp.int32),
+                      (per > 0).sum(dtype=jnp.int32)])
+
+
+def moe(params: Params, x: jax.Array, cfg) -> Tuple[jax.Array, jax.Array]:
+    """Top-k MoE: ``route``, the routed experts' output, plus the shared
+    experts (``cfg.shared_ff``).  Returns ``(out, held_counts)``.
+
+    ``cfg.experts_held`` set: the expert-parallel layer — this chip holds
+    ``experts_held`` of the ``n_experts`` it routes over and computes
+    their share with no token dropped (``held_experts``); what the experts
+    held elsewhere add is left out.  Otherwise every expert is computed
+    here by sort-based dispatch with PER-SEQUENCE capacity groups.
 
     Each sequence dispatches its own S·k token-expert pairs (argsort by
     expert, rank-within-expert, first C kept — GShard-style dropping).  The
@@ -429,14 +621,27 @@ def moe(params: Params, x: jax.Array, cfg) -> jax.Array:
     prefill).  The (B, E, C, D) buffer shards (data, ep?, -, -); expert d_ff
     shards over "model" in TP mode, the E axis does in EP mode.
     """
+    with jax.named_scope("moe_route"):
+        gate, expert_idx = route(params, x, cfg)
+    with jax.named_scope("moe_experts"):
+        if cfg.experts_held:
+            out = held_experts(params, x, gate, expert_idx, cfg)
+        else:
+            out = _capacity_dispatch(params, x, gate, expert_idx, cfg)
+    if cfg.shared_ff:
+        with jax.named_scope("moe_shared"):
+            out = out + mlp({"w_gate": params["shared_gate"],
+                             "w_up": params["shared_up"],
+                             "w_down": params["shared_down"]}, x, "swiglu")
+    out = logical_shard(out, "act_batch", "act_res_seq", "act_embed")
+    return out, held_counts(expert_idx, cfg)
+
+
+def _capacity_dispatch(params: Params, x: jax.Array, gate: jax.Array,
+                       expert_idx: jax.Array, cfg) -> jax.Array:
     B, S, D = x.shape
     E, K = cfg.n_experts, cfg.top_k
     C = max(8, int(S * K / E * cfg.capacity_factor))
-
-    logits = jnp.einsum("bsd,de->bse", x, params["w_router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, expert_idx = jax.lax.top_k(probs, K)  # (B, S, K)
-    gate = gate / jnp.maximum(gate.sum(-1, keepdims=True), 1e-9)
 
     pairs_e = expert_idx.reshape(B, S * K)
     order = jnp.argsort(pairs_e, axis=-1, stable=True)  # (B, S*K)
@@ -477,7 +682,7 @@ def moe(params: Params, x: jax.Array, cfg) -> jax.Array:
     out = jax.vmap(
         lambda st, rows: jnp.zeros((S, D), x.dtype).at[st].add(rows)
     )(src_token, gathered * w[..., None].astype(x.dtype))
-    return logical_shard(out, "act_batch", "act_res_seq", "act_embed")
+    return out
 
 
 def moe_aux_loss(params: Params, x: jax.Array, cfg) -> jax.Array:

@@ -5,7 +5,12 @@ Layer stacking: the repeating pattern unit (e.g. gemma3's 5×local+1×global,
 zamba2's 5×mamba+1×shared_attn) is scanned over ``n_repeats`` with parameters
 stacked on a leading "layers" dim — compile time is unit-sized, not
 depth-sized.  ``shared_attn`` positions close over ONE unstacked param set
-(Zamba2 weight sharing).  Tail layers run unrolled.
+(Zamba2 weight sharing).  Lead layers (``cfg.lead``, DeepSeek-V3's leading
+dense layer) run unrolled before the scan, tail layers after it.
+
+A model with "mla_moe" layers also carries ``caches["moe"]``: the last
+step's ``[held_pairs, experts_hit]`` summed over its expert layers
+(``layers.held_counts``), for the serving engine's counters.
 
 Caches (decode) are PyTrees with leading (n_repeats, ...) dims scanned along
 with the params; see ``repro.cache.paged_kv`` for the AWRP bounded pool.
@@ -74,14 +79,25 @@ def _mlp_decls(cfg) -> Dict[str, Decl]:
 
 
 def _moe_decls(cfg) -> Dict[str, Decl]:
-    d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    """Router over all ``n_experts``; the held experts' weights (all of
+    them unless ``experts_held``); the correction bias and shared experts
+    where the config has them."""
+    d, e = cfg.d_model, cfg.n_experts
+    ff, held = cfg.expert_ff or cfg.d_ff, cfg.experts_held or e
     out = {
         "w_router": Decl((d, e), ("p_embed", "p_noshard")),
-        "w_up": Decl((e, d, ff), ("p_experts", "p_embed", "p_expert_ff")),
-        "w_down": Decl((e, ff, d), ("p_experts", "p_expert_ff", "p_embed")),
+        "w_up": Decl((held, d, ff), ("p_experts", "p_embed", "p_expert_ff")),
+        "w_down": Decl((held, ff, d), ("p_experts", "p_expert_ff", "p_embed")),
     }
     if cfg.act == "swiglu":
-        out["w_gate"] = Decl((e, d, ff), ("p_experts", "p_embed", "p_expert_ff"))
+        out["w_gate"] = Decl((held, d, ff), ("p_experts", "p_embed", "p_expert_ff"))
+    if cfg.router_scoring == "sigmoid":
+        out["router_bias"] = Decl((e,), ("p_noshard",))
+    if cfg.shared_ff:
+        sf = cfg.shared_ff
+        out["shared_gate"] = Decl((d, sf), ("p_embed", "p_feat"))
+        out["shared_up"] = Decl((d, sf), ("p_embed", "p_feat"))
+        out["shared_down"] = Decl((sf, d), ("p_feat", "p_embed"))
     return out
 
 
@@ -119,11 +135,33 @@ def _cross_decls(cfg) -> Dict[str, Decl]:
     return out
 
 
+def _mla_decls(cfg) -> Dict[str, Decl]:
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": Decl((d, H * (dn + dr)), ("p_embed", "p_feat")),
+        "w_kva": Decl((d, r + dr), ("p_embed", "p_noshard")),
+        "kv_norm": Decl((r,), ("p_noshard",), "zeros"),
+        "w_kvb": Decl((r, H * (dn + dv)), ("p_noshard", "p_feat")),
+        "wo": Decl((H * dv, d), ("p_feat", "p_embed")),
+        "ln1": Decl((d,), ("p_noshard",), "zeros"),
+        "ln2": Decl((d,), ("p_noshard",), "zeros"),
+    }
+
+
+#: block kinds whose attention is MLA over one cached latent row per token
+MLA_KINDS = ("mla", "mla_moe")
+
+
 def block_decls(cfg, kind: str) -> Dict[str, Decl]:
     if kind in ("attn", "global", "local", "shared_attn"):
         return {**_attn_decls(cfg), **_mlp_decls(cfg)}
     if kind == "moe":
         return {**_attn_decls(cfg), **_moe_decls(cfg)}
+    if kind == "mla":
+        return {**_mla_decls(cfg), **_mlp_decls(cfg)}
+    if kind == "mla_moe":
+        return {**_mla_decls(cfg), **_moe_decls(cfg)}
     if kind == "mamba":
         return _mamba_decls(cfg)
     if kind == "enc":
@@ -133,17 +171,27 @@ def block_decls(cfg, kind: str) -> Dict[str, Decl]:
     raise ValueError(kind)
 
 
-def scan_plan(cfg) -> Tuple[List[Tuple[str, str]], int, List[Tuple[str, str]]]:
-    """Returns (unit, n_repeats, tail) where unit/tail entries are
-    (position_name, kind)."""
+Plan = List[Tuple[str, str]]
+
+
+def scan_plan(cfg) -> Tuple[Plan, Plan, int, Plan]:
+    """Returns (lead, unit, n_repeats, tail) where lead/unit/tail entries
+    are (position_name, kind); lead and tail run unrolled around the scan
+    of the unit."""
     if cfg.family == "encdec":
-        return [], 0, []
+        return [], [], 0, []
+    lead = [(f"l{i}", k) for i, k in enumerate(cfg.lead)]
     if cfg.pattern is None:
         kind = "moe" if cfg.n_experts else "attn"
-        return [("u0", kind)], cfg.n_layers, []
+        return lead, [("u0", kind)], cfg.n_layers - len(lead), []
     unit = [(f"u{i}", k) for i, k in enumerate(cfg.pattern)]
     tail = [(f"t{i}", k) for i, k in enumerate(cfg.tail)]
-    return unit, cfg.n_repeats, tail
+    return lead, unit, cfg.n_repeats, tail
+
+
+def has_expert_counts(cfg) -> bool:
+    """Whether the model's steps carry ``caches["moe"]``."""
+    return "mla_moe" in cfg.layer_pattern
 
 
 def param_decls(cfg) -> Dict[str, Any]:
@@ -169,7 +217,9 @@ def param_decls(cfg) -> Dict[str, Any]:
         tree["enc_final_norm"] = Decl((d,), ("p_noshard",), "zeros")
         return tree
 
-    unit, n_rep, tail = scan_plan(cfg)
+    lead, unit, n_rep, tail = scan_plan(cfg)
+    for pos, kind in lead:
+        tree[pos] = block_decls(cfg, kind)
     shared_done = False
     for pos, kind in unit:
         if kind == "shared_attn":
@@ -221,7 +271,7 @@ def init_params(cfg, key: jax.Array) -> Params:
         for p in path[:-1]:
             node = node.setdefault(p, {})
         # norm-ish params stay fp32 for stability
-        dt = jnp.float32 if decl.init in ("a_log", "dt_bias", "zeros", "ones") and len(decl.shape) <= 2 and decl.shape[-1] <= 16384 and path[-1] in ("ln1", "ln2", "ln3", "final_norm", "enc_final_norm", "norm_scale", "a_log", "dt_bias", "d_skip") else dtype
+        dt = jnp.float32 if decl.init in ("a_log", "dt_bias", "zeros", "ones") and len(decl.shape) <= 2 and decl.shape[-1] <= 16384 and path[-1] in ("ln1", "ln2", "ln3", "final_norm", "enc_final_norm", "norm_scale", "kv_norm", "a_log", "dt_bias", "d_skip") else dtype
         node[path[-1]] = _materialize(decl, kk, dt)
     return out
 
@@ -235,7 +285,8 @@ def abstract_params(cfg) -> Params:
             if isinstance(v, Decl):
                 dt = jnp.float32 if k in ("ln1", "ln2", "ln3", "final_norm",
                                           "enc_final_norm", "norm_scale",
-                                          "a_log", "dt_bias", "d_skip") else dtype
+                                          "kv_norm", "a_log", "dt_bias",
+                                          "d_skip") else dtype
                 out[k] = jax.ShapeDtypeStruct(v.shape, dt)
             else:
                 out[k] = to_sds(v)
@@ -260,52 +311,83 @@ def param_logical_axes(cfg) -> Dict[str, Any]:
 
 
 def _apply_block(kind: str, p: Params, x: jax.Array, cfg, positions, collect_cache):
+    """One block over a whole sequence: (x, cache or None, expert counts or
+    None)."""
     if kind == "mamba":
         h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
         y, state, conv = L.mamba2_block(p, h, cfg)
         cache = {"state": state, "conv": conv} if collect_cache else None
-        return x + y, cache
-    window = cfg.sliding_window if kind == "local" else 0
+        return x + y, cache, None
     h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
-    attn_out, (k, v) = L.attention(
-        p, h, cfg, positions=positions, causal=True, window=window
-    )
+    if kind in MLA_KINDS:
+        attn_out, lat = L.mla_attention(p, h, cfg, positions=positions)
+        cache = {"c": lat} if collect_cache else None
+    else:
+        window = cfg.sliding_window if kind == "local" else 0
+        attn_out, (k, v) = L.attention(
+            p, h, cfg, positions=positions, causal=True, window=window
+        )
+        cache = {"k": k.reshape(k.shape[0], k.shape[1], -1),
+                 "v": v.reshape(v.shape[0], v.shape[1], -1)} if collect_cache else None
     x = x + attn_out
     h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-    ff = L.moe(p, h2, cfg) if kind == "moe" else L.mlp(p, h2, cfg.act)
-    x = x + ff
-    cache = {"k": k.reshape(k.shape[0], k.shape[1], -1),
-             "v": v.reshape(v.shape[0], v.shape[1], -1)} if collect_cache else None
-    return x, cache
+    counts = None
+    if kind == "mla_moe":
+        ff, counts = L.moe(p, h2, cfg)
+    elif kind == "moe":
+        ff, _ = L.moe(p, h2, cfg)
+    else:
+        ff = L.mlp(p, h2, cfg.act)
+    return x + ff, cache, counts
+
+
+def _add_counts(total, counts):
+    if counts is None:
+        return total
+    return counts if total is None else total + counts
 
 
 def _stack_scan(params, x, cfg, positions, collect_cache):
-    """Scan the pattern unit over n_repeats, then run the tail."""
-    unit, n_rep, tail = scan_plan(cfg)
+    """Run the lead layers, scan the pattern unit over n_repeats, then run
+    the tail.  Returns (x, (lead, unit, tail caches), expert counts)."""
+    lead, unit, n_rep, tail = scan_plan(cfg)
     stacked = {pos: params[pos] for pos, kind in unit if kind != "shared_attn"}
     shared = params.get("shared_attn")
+    counts = None
+    lead_caches = {}
+    for pos, kind in lead:
+        x, cache, c = _apply_block(kind, params[pos], x, cfg, positions,
+                                   collect_cache)
+        counts = _add_counts(counts, c)
+        if collect_cache and cache is not None:
+            lead_caches[pos] = cache
 
     def body(carry, slices):
         h = carry
-        caches = {}
+        caches, unit_counts = {}, None
         for pos, kind in unit:
             p = shared if kind == "shared_attn" else slices[pos]
-            h, cache = _apply_block(kind, p, h, cfg, positions, collect_cache)
+            h, cache, c = _apply_block(kind, p, h, cfg, positions, collect_cache)
+            unit_counts = _add_counts(unit_counts, c)
             if collect_cache and cache is not None:
                 caches[pos] = cache
-        return h, caches if collect_cache else None
+        return h, (caches if collect_cache else None, unit_counts)
 
     if cfg.remat == "full" and not collect_cache:
         # recompute block interiors in backward: only layer-boundary carries
         # are saved across the depth scan (flash chunks re-checkpoint inside)
         body = jax.checkpoint(body)
-    x, unit_caches = jax.lax.scan(body, x, stacked, length=n_rep)
+    x, (unit_caches, unit_counts) = jax.lax.scan(body, x, stacked, length=n_rep)
+    if unit_counts is not None:
+        counts = _add_counts(counts, unit_counts.sum(0))
     tail_caches = {}
     for pos, kind in tail:
-        x, cache = _apply_block(kind, params[pos], x, cfg, positions, collect_cache)
+        x, cache, c = _apply_block(kind, params[pos], x, cfg, positions,
+                                   collect_cache)
+        counts = _add_counts(counts, c)
         if collect_cache and cache is not None:
             tail_caches[pos] = cache
-    return x, (unit_caches, tail_caches)
+    return x, (lead_caches, unit_caches, tail_caches), counts
 
 
 def _encdec_forward(params, cfg, frames, tokens, collect_cache):
@@ -388,7 +470,7 @@ def forward(params: Params, cfg, batch: Dict[str, jax.Array]) -> jax.Array:
                              x[:, cfg.n_patch_tokens:]], axis=1)
     x = logical_shard(x, "act_batch", "act_res_seq", "act_embed")
     positions = jnp.arange(S, dtype=jnp.int32)
-    x, _ = _stack_scan(params, x, cfg, positions, collect_cache=False)
+    x, _, _ = _stack_scan(params, x, cfg, positions, collect_cache=False)
     return logits_from_hidden(params, cfg, x)
 
 
@@ -428,6 +510,16 @@ def _decode_cache_decl(cfg, kind: str, batch: int, max_len: int, kv_mode: str,
     if kind == "local":
         W = cfg.sliding_window
         return {"k": make((batch, W, kvd), dtype), "v": make((batch, W, kvd), dtype)}
+    if kind in MLA_KINDS:
+        width = cfg.kv_lora_rank + cfg.qk_rope_head_dim
+        if kv_mode == "paged":
+            if cfg.kv_policy in paged_kv.TRUE_ADAPTIVE_KV:
+                raise ValueError("MLA's latent pool takes the classic policies "
+                                 f"only, not {cfg.kv_policy!r}")
+            return paged_kv.latent_pool(batch, cfg.bounded_kv_pages,
+                                        cfg.page_size, width, dtype,
+                                        abstract=abstract)
+        return {"c": make((batch, max_len, width), dtype)}
     # full-attention kinds
     if kv_mode == "paged":
         if cfg.kv_policy in paged_kv.TRUE_ADAPTIVE_KV:
@@ -468,26 +560,37 @@ def decode_caches(cfg, batch: int, max_len: int, *, kv_mode: str = "full",
                          for k, s in dec.items()}
         return {"pos": make_scalar(), "blocks": blocks}
 
-    unit, n_rep, tail = scan_plan(cfg)
+    lead, unit, n_rep, tail = scan_plan(cfg)
     for pos, kind in unit:
         blocks[pos] = add_stack(
             _decode_cache_decl(cfg, kind, batch, max_len, kv_mode, abstract), n_rep)
-    for pos, kind in tail:
+    for pos, kind in lead + tail:
         blocks[pos] = _decode_cache_decl(cfg, kind, batch, max_len, kv_mode, abstract)
-    return {"pos": make_scalar(), "blocks": blocks}
+    out = {"pos": make_scalar(), "blocks": blocks}
+    if has_expert_counts(cfg):
+        out["moe"] = (jax.ShapeDtypeStruct((2,), jnp.int32) if abstract
+                      else jnp.zeros((2,), jnp.int32))
+    return out
 
 
 def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
                   win_positions, kv_mode: str, fused: bool = False,
                   mesh=None):
+    """One block of one decode step: (x, new cache, expert counts or
+    None)."""
     B = x.shape[0]
     if kind == "mamba":
         h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
         y, st, cv = L.mamba2_decode_step(p, h, cfg, state=cache["state"],
                                          conv_state=cache["conv"])
-        return x + y, {"state": st, "conv": cv}
+        return x + y, {"state": st, "conv": cv}, None
 
     h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
+    if kind in MLA_KINDS:
+        if fused:
+            raise ValueError("the fused policy-attention kernel serves K/V "
+                             "pools only; MLA's latent pool runs unfused")
+        return _decode_mla_block(kind, p, x, h, cfg, cache, pos, kv_mode)
     nk, nv = L.decode_kv_row(p, h, cfg, position=pos)
     if kind == "local":
         k, v = paged_kv.ring_insert(cache["k"], cache["v"], nk, nv, pos)
@@ -546,8 +649,40 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
         new_cache = {"k": k, "v": v}
     x = x + attn_out
     h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
-    ff = L.moe(p, h2, cfg) if kind == "moe" else L.mlp(p, h2, cfg.act)
-    return x + ff, new_cache
+    ff = L.moe(p, h2, cfg)[0] if kind == "moe" else L.mlp(p, h2, cfg.act)
+    return x + ff, new_cache, None
+
+
+def _decode_mla_block(kind: str, p: Params, x, h, cfg, cache, pos,
+                      kv_mode: str):
+    """An MLA block's decode step: the token's latent row into the cache
+    (the latent pool, evicting by ``cfg.kv_policy``, or the full cache),
+    absorbed attention over the rows, then the MLP or the expert layer."""
+    B = x.shape[0]
+    row = L.mla_latent(p, h, cfg, jnp.full((B, 1), pos, jnp.int32))[:, 0]
+    if kv_mode == "paged":
+        page = cfg.page_size
+        pool = paged_kv.insert_latent(cache, row, pos, page,
+                                      policy=cfg.kv_policy)
+        rows = pool.c.reshape(B, -1, pool.c.shape[-1])
+        kv_pos = paged_kv.kv_positions(pool, pos, page)
+        attn_out, mass = L.mla_decode_attend(p, h, cfg, position=pos,
+                                             cache=rows, kv_positions=kv_pos)
+        new_cache = paged_kv.score_update(pool, mass, page)
+    else:
+        c = jax.lax.dynamic_update_slice_in_dim(cache["c"], row[:, None], pos,
+                                                axis=1)
+        t = jnp.arange(c.shape[1], dtype=jnp.int32)
+        kv_pos = jnp.broadcast_to(jnp.where(t <= pos, t, -1)[None], (B, c.shape[1]))
+        attn_out, _ = L.mla_decode_attend(p, h, cfg, position=pos, cache=c,
+                                          kv_positions=kv_pos)
+        new_cache = {"c": c}
+    x = x + attn_out
+    h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
+    if kind == "mla_moe":
+        ff, counts = L.moe(p, h2, cfg)
+        return x + ff, new_cache, counts
+    return x + L.mlp(p, h2, cfg.act), new_cache, None
 
 
 def _encdec_decode(params, cfg, token, caches):
@@ -606,29 +741,43 @@ def decode_step(params: Params, cfg, token: jax.Array, caches,
     win_positions = (paged_kv.ring_positions(pos, cfg.sliding_window)
                      if cfg.sliding_window else None)
 
-    unit, n_rep, tail = scan_plan(cfg)
+    lead, unit, n_rep, tail = scan_plan(cfg)
     stacked_params = {p: params[p] for p, k in unit if k != "shared_attn"}
     stacked_caches = {p: caches["blocks"][p] for p, k in unit}
+    new_blocks, counts = {}, None
+    for pname, kind in lead:
+        x, new_blocks[pname], c = _decode_block(
+            kind, params[pname], x, cfg, caches["blocks"][pname], pos,
+            win_positions, kv_mode, fused, mesh)
+        counts = _add_counts(counts, c)
 
     def body(carry, xs):
         h = carry
         pslices, cslices = xs
-        new_caches = {}
+        new_caches, unit_counts = {}, None
         for pname, kind in unit:
             prm = params["shared_attn"] if kind == "shared_attn" else pslices[pname]
-            h, new_caches[pname] = _decode_block(
+            h, new_caches[pname], c = _decode_block(
                 kind, prm, h, cfg, cslices[pname], pos, win_positions,
                 kv_mode, fused, mesh)
-        return h, new_caches
+            unit_counts = _add_counts(unit_counts, c)
+        return h, (new_caches, unit_counts)
 
-    x, new_stacked = jax.lax.scan(body, x, (stacked_params, stacked_caches))
-    new_blocks = dict(new_stacked)
+    x, (new_stacked, unit_counts) = jax.lax.scan(
+        body, x, (stacked_params, stacked_caches))
+    if unit_counts is not None:
+        counts = _add_counts(counts, unit_counts.sum(0))
+    new_blocks.update(new_stacked)
     for pname, kind in tail:
-        x, new_blocks[pname] = _decode_block(
+        x, new_blocks[pname], c = _decode_block(
             kind, params[pname], x, cfg, caches["blocks"][pname], pos,
             win_positions, kv_mode, fused, mesh)
+        counts = _add_counts(counts, c)
     logits = logits_from_hidden(params, cfg, x)
-    return logits, {"pos": pos + 1, "blocks": new_blocks}
+    out = {"pos": pos + 1, "blocks": new_blocks}
+    if counts is not None:
+        out["moe"] = counts
+    return logits, out
 
 
 # ---------------------------------------------------------------------------
@@ -638,12 +787,14 @@ def decode_step(params: Params, cfg, token: jax.Array, caches,
 
 def prefill(params: Params, cfg, batch: Dict[str, jax.Array], max_len: int,
             *, kv_mode: str = "full"):
-    """Run the full prompt, return (logits, decode caches positioned at S).
-    For kv_mode="paged" the prompt must be page-aligned (engine enforces)."""
+    """Run the full prompt, return (logits at the last position (B, 1,
+    Vpad), decode caches positioned at S) — the head runs on the one
+    position decoding continues from.  For kv_mode="paged" the prompt must
+    be page-aligned (engine enforces)."""
     if cfg.family == "encdec":
         x, enc_out, dec_caches = _encdec_forward(
             params, cfg, batch["frames"], batch["tokens"], collect_cache=True)
-        logits = logits_from_hidden(params, cfg, x)
+        logits = logits_from_hidden(params, cfg, x[:, -1:])
         B, Sd = batch["tokens"].shape
         pad = max_len - Sd
         new = {
@@ -661,19 +812,25 @@ def prefill(params: Params, cfg, batch: Dict[str, jax.Array], max_len: int,
                              x[:, cfg.n_patch_tokens:]], axis=1)
     x = logical_shard(x, "act_batch", "act_res_seq", "act_embed")
     positions = jnp.arange(S, dtype=jnp.int32)
-    x, (unit_caches, tail_caches) = _stack_scan(params, x, cfg, positions,
-                                                collect_cache=True)
-    logits = logits_from_hidden(params, cfg, x)
+    x, (lead_caches, unit_caches, tail_caches), counts = _stack_scan(
+        params, x, cfg, positions, collect_cache=True)
+    logits = logits_from_hidden(params, cfg, x[:, -1:])
 
-    unit, n_rep, tail = scan_plan(cfg)
-    kinds = dict(unit + tail)
+    lead, unit, n_rep, tail = scan_plan(cfg)
+    kinds = dict(lead + unit + tail)
 
     def convert(pos_name, cache, stacked):
         kind = kinds[pos_name]
         if kind == "mamba":
             return cache  # {"state", "conv"} already decode layout
-        k, v = cache["k"], cache["v"]
         seq_ax = 2 if stacked else 1
+        if kind in MLA_KINDS:
+            if kv_mode == "paged":
+                return pool_from_prefill(cfg, {"c": cache["c"]}, S, stacked)
+            cfgpad = [(0, 0)] * cache["c"].ndim
+            cfgpad[seq_ax] = (0, max_len - S)
+            return {"c": jnp.pad(cache["c"], cfgpad)}
+        k, v = cache["k"], cache["v"]
         if kind == "local":
             W = cfg.sliding_window
             start = max(S - W, 0)
@@ -689,44 +846,54 @@ def prefill(params: Params, cfg, batch: Dict[str, jax.Array], max_len: int,
                 kr, vr = kr.at[:, slots].set(ksl), vr.at[:, slots].set(vsl)
             return {"k": kr, "v": vr}
         if kv_mode == "paged":
-            return pool_from_prefill(cfg, k, v, S, stacked)
+            return pool_from_prefill(cfg, {"k": k, "v": v}, S, stacked)
         pad = max_len - S
         cfgpad = [(0, 0)] * k.ndim
         cfgpad[seq_ax] = (0, pad)
         return {"k": jnp.pad(k, cfgpad), "v": jnp.pad(v, cfgpad)}
 
     blocks = {p: convert(p, c, True) for p, c in unit_caches.items()}
-    blocks.update({p: convert(p, c, False) for p, c in tail_caches.items()})
-    return logits, {"pos": jnp.asarray(S, jnp.int32), "blocks": blocks}
+    blocks.update({p: convert(p, c, False)
+                   for p, c in {**lead_caches, **tail_caches}.items()})
+    out = {"pos": jnp.asarray(S, jnp.int32), "blocks": blocks}
+    if counts is not None:
+        out["moe"] = counts
+    return logits, out
 
 
-def pool_from_prefill(cfg, k, v, S: int, stacked: bool):
-    """Seed an AWRP pool from prefill KV: the last `pages` page-aligned pages
-    are resident with F=1 and R = page creation order (documented seeding —
-    the policy then evolves scores during decode)."""
+def pool_from_prefill(cfg, planes: Dict[str, jax.Array], S: int,
+                      stacked: bool):
+    """Seed an AWRP pool from prefill rows, ``planes`` {"k", "v"} (a K/V
+    pool) or {"c"} (a latent pool), each (B, S, width): the last `pages`
+    page-aligned pages are resident with F=1 and R = page creation order
+    (documented seeding — the policy then evolves scores during decode)."""
     page, P = cfg.page_size, cfg.bounded_kv_pages
     n_have = S // page  # prompt must be page-aligned (asserted by engine)
     n_res = min(n_have, P)
     start_tok = (n_have - n_res) * page
 
-    def one(k2, v2):  # (B, S, kvd)
-        B, _, kvd = k2.shape
-        ksl = k2[:, start_tok : start_tok + n_res * page].reshape(B, n_res, page, kvd)
-        vsl = v2[:, start_tok : start_tok + n_res * page].reshape(B, n_res, page, kvd)
-        kp = jnp.zeros((B, P, page, kvd), k2.dtype).at[:, :n_res].set(ksl)
-        vp = jnp.zeros((B, P, page, kvd), v2.dtype).at[:, :n_res].set(vsl)
+    def one(pl):  # {name: (B, S, width)}
+        B = next(iter(pl.values())).shape[0]
+
+        def paged(x):
+            sl = x[:, start_tok : start_tok + n_res * page].reshape(
+                B, n_res, page, x.shape[-1])
+            return jnp.zeros((B, P, page, x.shape[-1]), x.dtype).at[:, :n_res].set(sl)
+
         order = jnp.arange(P, dtype=jnp.int32)
         f = jnp.where(order < n_res, 1, 0).astype(jnp.int32)
         r = jnp.where(order < n_res, order + 1, 0).astype(jnp.int32)
         starts = jnp.where(order < n_res, start_tok + order * page, -1).astype(jnp.int32)
-        pool = paged_kv.PagedPool(
-            k=kp, v=vp,
+        meta = dict(
             f=jnp.broadcast_to(f, (B, P)),
             r=jnp.broadcast_to(r, (B, P)),
             page_start=jnp.broadcast_to(starts, (B, P)),
             clock=jnp.full((B,), n_res, jnp.int32),
             open_slot=jnp.full((B,), max(n_res - 1, 0), jnp.int32),
         )
+        if "c" in pl:
+            return paged_kv.LatentPagedPool(c=paged(pl["c"]), **meta)
+        pool = paged_kv.PagedPool(k=paged(pl["k"]), v=paged(pl["v"]), **meta)
         if cfg.kv_policy in paged_kv.TRUE_ADAPTIVE_KV:
             return paged_kv.AdaptivePagedPool(
                 pool=pool,
@@ -735,6 +902,4 @@ def pool_from_prefill(cfg, k, v, S: int, stacked: bool):
             )
         return pool
 
-    if stacked:
-        return jax.vmap(one)(k, v)
-    return one(k, v)
+    return jax.vmap(one)(planes) if stacked else one(planes)

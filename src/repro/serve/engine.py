@@ -50,6 +50,7 @@ from repro.core import sharding
 from repro.cache import paged_kv
 from repro.cache.paged_kv import AdaptivePagedPool
 from repro.cache.prefix_cache import PrefixCache
+from repro.models import layers as L
 from repro.models import model as M
 from repro.obs import profiling
 from repro.obs.metrics import Derived, Registry, loop_planes, loop_update, safe_ratio
@@ -98,9 +99,14 @@ def _is_apool(x) -> bool:
 
 
 def _kv_step(old_caches, new_caches) -> jax.Array:
-    """One decode step's paged-KV ``[hits, evictions]`` (int32, bounded by
-    layers x sequences x pages); the engine sums the steps on the host."""
-    return jnp.stack(paged_kv.step_counts(old_caches, new_caches))
+    """One decode step's row of counts: paged-KV ``[hits, evictions]``
+    (int32, bounded by layers x sequences x pages), then, for a model with
+    expert layers, the step's ``[held_pairs, experts_hit]``
+    (``caches["moe"]``); the engine sums the steps on the host."""
+    row = jnp.stack(paged_kv.step_counts(old_caches, new_caches))
+    if "moe" in new_caches:
+        row = jnp.concatenate([row, new_caches["moe"]])
+    return row
 
 
 class ServeEngine:
@@ -193,7 +199,7 @@ class ServeEngine:
         #: count is the engine-wide loop trace total and cache_size the
         #: total compiled-bucket count
         self._loops: Dict[int, object] = {}
-        self._loop_sentinel = Sentinel("decode_loop")
+        self._loop_sentinel = Sentinel("decode_loop", scopes=L.SERVE_SCOPES)
         self.stats = {"prefills": 0, "decode_steps": 0, "tokens": 0,
                       "shed": 0, "deferred": 0, "kv_ghost_hits": 0,
                       "rebalances": 0}
@@ -214,6 +220,14 @@ class ServeEngine:
         #: per-step counts and the host adds them here as Python ints, so
         #: the totals never wrap however long the engine serves
         self._kv_counts = {"hits": 0, "evictions": 0}
+        #: expert-layer work of models that have it (``caches["moe"]``):
+        #: token-expert pairs routed to the experts held here and held
+        #: experts with at least one pair, summed over layers and calls —
+        #: prefill from its caches, decode from the loops' per-step rows
+        self._moe_counts = (
+            {ph: {"held_pairs": 0, "experts_hit": 0}
+             for ph in ("prefill", "decode")}
+            if M.has_expert_counts(cfg) else None)
         self._kv_step = jax.jit(_kv_step)
         #: host timing spans around the serving sections (prefill / decode /
         #: rebalance / trace_drain) — mounted on the registry like the
@@ -391,6 +405,8 @@ class ServeEngine:
         else:
             self.registry.mount("tenant", self._tenant_provider)
         self.registry.mount("kv", self._kv_provider)
+        self.registry.mount("moe", lambda: (
+            self._moe_counts if self.metrics and self._moe_counts else {}))
         self.registry.mount(
             "expert",
             lambda: (
@@ -434,11 +450,20 @@ class ServeEngine:
         return out
 
     def _add_kv_counts(self, per_step) -> None:
-        """Fold ``(steps, 2)`` per-step ``[hits, evictions]`` into the
-        engine's Python-int totals (one pull, after the tokens' own)."""
-        hits, evictions = np.asarray(per_step, np.int64).reshape(-1, 2).sum(0)
-        self._kv_counts["hits"] += int(hits)
-        self._kv_counts["evictions"] += int(evictions)
+        """Fold per-step rows (``_kv_step``: ``[hits, evictions]``, then
+        the expert counts where the model has them) into the engine's
+        Python-int totals (one pull, after the tokens' own)."""
+        a = np.asarray(per_step, np.int64)
+        tot = a.reshape(-1, a.shape[-1]).sum(0)
+        self._kv_counts["hits"] += int(tot[0])
+        self._kv_counts["evictions"] += int(tot[1])
+        if self._moe_counts is not None:
+            self._add_moe_counts("decode", tot[2:])
+
+    def _add_moe_counts(self, phase: str, counts) -> None:
+        held_pairs, experts_hit = np.asarray(counts, np.int64)
+        self._moe_counts[phase]["held_pairs"] += int(held_pairs)
+        self._moe_counts[phase]["experts_hit"] += int(experts_hit)
 
     def _kv_provider(self) -> dict:
         if self.kv_mode != "paged":
@@ -683,6 +708,12 @@ class ServeEngine:
                 )
                 self._insert_prefix(reqs[0], payload)
 
+        prefill_counts = None
+        if self._moe_counts is not None:
+            # the loop carries the step's counts; the prefill's leave here
+            # (a prefix hit did no prefill work)
+            prefill_counts = None if was_cached else caches["moe"]
+            caches = {**caches, "moe": jnp.zeros_like(caches["moe"])}
         caches = self._shard_caches(caches, len(reqs))
         if self.jit_loop:
             loop = self._get_loop(max_new)
@@ -727,6 +758,8 @@ class ServeEngine:
                     [np.asarray(t) for t in generated], axis=1)
             if self.metrics and kv:
                 self._add_kv_counts(jnp.stack(kv))
+        if self.metrics and prefill_counts is not None:
+            self._add_moe_counts("prefill", prefill_counts)
         if single and self._ghost_feed_on:
             self._kv_persist(caches, reqs[0].tenant_id)
         dt = time.time() - t0
