@@ -10,7 +10,10 @@ mass exceeds tau = 1/num_resident_pages; eviction on pool-full allocation is
 
 All arrays carry leading (B,) — one policy instance per sequence — and the
 model stacks a further (n_repeats,) layer dim scanned by lax.scan (one policy
-instance per layer, since attention mass differs per layer).
+instance per layer, since attention mass differs per layer).  The row planes
+(``ROW_PLANES``) ride that scan's carry whole: the functions that write them
+take ``layer``, the index of the layer's pool in the stack (``()`` for a pool
+of one layer), and write only the new token's row, in place.
 
 Two eviction modes (both policy-pluggable through the unified core,
 DESIGN.md §7):
@@ -57,9 +60,51 @@ INT_MAX = 2**31 - 1
 #: kv_policy names served by the true-adaptive pool mode -> core policy
 TRUE_ADAPTIVE_KV = {"arc_adaptive": "arc", "car_adaptive": "car"}
 
+#: the planes of a decode cache that hold one row per cached token: a pool's
+#: or a contiguous cache's ``k``/``v``, a latent cache's ``c``.  A decode
+#: step writes them by row, in place; every other plane is small and is
+#: written whole.
+ROW_PLANES = ("k", "v", "c")
+
+
+def split_rows(tree):
+    """``(rows, rest, how)``: the leaves of a cache tree that are row planes
+    (``ROW_PLANES``), the other leaves, both in traversal order, and what
+    ``merge_rows`` needs to put the tree back together."""
+    paths, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    # a NamedTuple field's key has a ``name``, a dict entry's a ``key``
+    is_row = tuple(getattr(path[-1], "name", getattr(path[-1], "key", None))
+                   in ROW_PLANES for path, _ in paths)
+    rows = [x for (_, x), r in zip(paths, is_row) if r]
+    rest = [x for (_, x), r in zip(paths, is_row) if not r]
+    return rows, rest, (treedef, is_row)
+
+
+def merge_rows(rows, rest, how):
+    """The cache tree ``split_rows`` took apart, from its two lists."""
+    treedef, is_row = how
+    rows, rest = iter(rows), iter(rest)
+    return treedef.unflatten([next(rows) if r else next(rest) for r in is_row])
+
+
+def write_token(planes, rows, layer, need_alloc, slot, within):
+    """Write each sequence's new token row into a pool's row planes, in
+    place: ``rows[i]`` (B, width) goes to ``planes[i][*layer, b, slot[b],
+    within]``.  On a page boundary (``need_alloc``) the allocated pages are
+    zeroed first; between boundaries that write points past the pool and is
+    dropped, so a step writes nothing but its rows."""
+    bidx = jnp.arange(slot.shape[0])
+    pages = planes[0].shape[len(layer) + 1]
+    reset = (*layer, bidx, jnp.where(need_alloc, slot, pages))
+    return tuple(p.at[reset].set(0, mode="drop")
+                 .at[(*layer, bidx, slot, within)].set(row)
+                 for p, row in zip(planes, rows))
+
 
 class PagedPool(NamedTuple):
-    """Per-layer-per-sequence bounded KV pool."""
+    """Per-layer-per-sequence bounded KV pool.  Inside ``decode_step``'s
+    layer scan ``k`` and ``v`` are the whole layer stack (module
+    docstring)."""
 
     k: jax.Array  # (B, P, page, kvd)
     v: jax.Array  # (B, P, page, kvd)
@@ -166,24 +211,15 @@ def insert_token(
     pos: jax.Array,  # scalar int32 — token index being written
     page_size: int,
     policy: str = "awrp",
+    layer: Tuple = (),
 ) -> PagedPool:
     """Write one token row; on page-boundary allocate (evicting by ``policy``
-    when the pool is full).  Branch-free — runs under jit/scan."""
-    B = pool.f.shape[0]
+    when the pool is full).  ``layer`` picks the pool's layer when its K/V
+    planes are a layer stack (module docstring).  Runs under jit/scan."""
     within, need_alloc, slot, f, r, page_start = _allocate(
         pool, pos, page_size, policy)
-    bidx = jnp.arange(B)
-    zero_row = jnp.zeros_like(pool.k[:, 0])  # (B, page, kvd)
-    k = pool.k.at[bidx, slot].set(
-        jnp.where(need_alloc[..., None, None] if need_alloc.ndim else need_alloc,
-                  zero_row, pool.k[bidx, slot])
-    )
-    v = pool.v.at[bidx, slot].set(
-        jnp.where(need_alloc[..., None, None] if need_alloc.ndim else need_alloc,
-                  zero_row, pool.v[bidx, slot])
-    )
-    k = k.at[bidx, slot, within].set(new_k)
-    v = v.at[bidx, slot, within].set(new_v)
+    k, v = write_token((pool.k, pool.v), (new_k, new_v), layer, need_alloc,
+                       slot, within)
     open_slot = jnp.where(need_alloc, slot, pool.open_slot).astype(jnp.int32)
     return PagedPool(k, v, f, r, page_start, pool.clock, open_slot)
 
@@ -225,16 +261,13 @@ def insert_latent(
     pos: jax.Array,  # scalar int32
     page_size: int,
     policy: str = "awrp",
+    layer: Tuple = (),
 ) -> LatentPagedPool:
     """``insert_token`` for a latent pool: the same allocation and
     eviction (``_allocate``), one plane written."""
-    B = pool.f.shape[0]
     within, need_alloc, slot, f, r, page_start = _allocate(
         pool, pos, page_size, policy)
-    bidx = jnp.arange(B)
-    c = pool.c.at[bidx, slot].set(
-        jnp.where(need_alloc, jnp.zeros_like(pool.c[:, 0]), pool.c[bidx, slot]))
-    c = c.at[bidx, slot, within].set(row)
+    (c,) = write_token((pool.c,), (row,), layer, need_alloc, slot, within)
     open_slot = jnp.where(need_alloc, slot, pool.open_slot).astype(jnp.int32)
     return LatentPagedPool(c, f, r, page_start, pool.clock, open_slot)
 
@@ -575,6 +608,7 @@ def adaptive_insert_token(
     pos: jax.Array,  # scalar int32 — token index being written
     page_size: int,
     core: AdaptiveCore,
+    layer: Tuple = (),
 ) -> AdaptivePagedPool:
     """``insert_token`` with TRUE arc/car eviction: a page-boundary
     allocation is one complete-miss access of the new page id; the policy's
@@ -618,15 +652,8 @@ def adaptive_insert_token(
     page_start = pool.page_start.at[bidx, slot].set(
         jnp.where(need_alloc, pos, pool.page_start[bidx, slot])
     )
-    zero_row = jnp.zeros_like(pool.k[:, 0])  # (B, page, kvd)
-    k = pool.k.at[bidx, slot].set(
-        jnp.where(need_alloc, zero_row, pool.k[bidx, slot])
-    )
-    v = pool.v.at[bidx, slot].set(
-        jnp.where(need_alloc, zero_row, pool.v[bidx, slot])
-    )
-    k = k.at[bidx, slot, within].set(new_k)
-    v = v.at[bidx, slot, within].set(new_v)
+    k, v = write_token((pool.k, pool.v), (new_k, new_v), layer, need_alloc,
+                       slot, within)
     open_slot = jnp.where(need_alloc, slot, pool.open_slot).astype(jnp.int32)
     return AdaptivePagedPool(
         pool=PagedPool(k, v, f, r, page_start, pool.clock, open_slot),
@@ -666,22 +693,14 @@ def adaptive_score_update(
 # ---------------------------------------------------------------------------
 
 
-def _scatter_new_token(pool: PagedPool, new_k, new_v, pos, page_size,
+def _scatter_new_token(pool: PagedPool, new_k, new_v, pos, page_size, layer,
                        slot, f, r, page_start, clock, open_slot) -> PagedPool:
     """Apply the kernel's allocation decision to the K/V arrays — the same
     zero-page + row-write ``insert_token`` performs, driven by the returned
     ``slot`` (the kernel keeps the pool K/V read-only; see DESIGN.md §10)."""
-    B = pool.k.shape[0]
     within = (pos % page_size).astype(jnp.int32)
-    need_alloc = within == 0
-    bidx = jnp.arange(B)
-    zero_row = jnp.zeros_like(pool.k[:, 0])
-    k = pool.k.at[bidx, slot].set(
-        jnp.where(need_alloc, zero_row, pool.k[bidx, slot]))
-    k = k.at[bidx, slot, within].set(new_k)
-    v = pool.v.at[bidx, slot].set(
-        jnp.where(need_alloc, zero_row, pool.v[bidx, slot]))
-    v = v.at[bidx, slot, within].set(new_v)
+    k, v = write_token((pool.k, pool.v), (new_k, new_v), layer, within == 0,
+                       slot, within)
     return PagedPool(k=k, v=v, f=f, r=r, page_start=page_start, clock=clock,
                      open_slot=open_slot)
 
@@ -717,6 +736,7 @@ def fused_decode_step(
     page_size: int,
     policy: str = "awrp",
     *,
+    layer: Tuple = (),
     mesh=None,
     interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array, PagedPool]:
@@ -730,8 +750,8 @@ def fused_decode_step(
 
     B, P = pool.f.shape
     KVH, G, hd = q.shape[1:]
-    kp = pool.k.reshape(B, P, page_size, KVH, hd)
-    vp = pool.v.reshape(B, P, page_size, KVH, hd)
+    kp = pool.k[layer].reshape(B, P, page_size, KVH, hd)
+    vp = pool.v[layer].reshape(B, P, page_size, KVH, hd)
     nk = new_k.reshape(B, KVH, hd)
     nv = new_v.reshape(B, KVH, hd)
     pos = jnp.asarray(pos, jnp.int32)
@@ -745,7 +765,7 @@ def fused_decode_step(
             pool.open_slot, pos.reshape(1))
     call = _shard_wrap(call, mesh, B, args, 10)
     out, mass, slot, f2, r2, ps2, clock2, open2 = call(*args)
-    new_pool = _scatter_new_token(pool, new_k, new_v, pos, page_size,
+    new_pool = _scatter_new_token(pool, new_k, new_v, pos, page_size, layer,
                                   slot, f2, r2, ps2, clock2, open2)
     return out, mass, new_pool
 
@@ -759,6 +779,7 @@ def fused_adaptive_decode_step(
     page_size: int,
     core: AdaptiveCore,
     *,
+    layer: Tuple = (),
     mesh=None,
     interpret: bool | None = None,
 ) -> Tuple[jax.Array, jax.Array, AdaptivePagedPool]:
@@ -774,8 +795,8 @@ def fused_adaptive_decode_step(
     B, P = pool.f.shape
     KVH, G, hd = q.shape[1:]
     L = pstate.blocks.shape[-1]
-    kp = pool.k.reshape(B, P, page_size, KVH, hd)
-    vp = pool.v.reshape(B, P, page_size, KVH, hd)
+    kp = pool.k[layer].reshape(B, P, page_size, KVH, hd)
+    vp = pool.v[layer].reshape(B, P, page_size, KVH, hd)
     nk = new_k.reshape(B, KVH, hd)
     nv = new_v.reshape(B, KVH, hd)
     pos = jnp.asarray(pos, jnp.int32)
@@ -794,7 +815,7 @@ def fused_adaptive_decode_step(
     call = _shard_wrap(call, mesh, B, args, 16)
     (out, mass, slot, f2, r2, ps2, clock2, open2,
      blk2, tag2, stp2, ref2, pp2, ctr2) = call(*args)
-    new_pool = _scatter_new_token(pool, new_k, new_v, pos, page_size,
+    new_pool = _scatter_new_token(pool, new_k, new_v, pos, page_size, layer,
                                   slot, f2, r2, ps2, clock2, open2)
     new_state = AdaptiveState(
         blocks=blk2[:, None], tag=tag2[:, None], stamp=stp2[:, None],
@@ -807,34 +828,18 @@ def fused_adaptive_decode_step(
 # ---------------------------------------------------------------------------
 
 
-def full_cache_insert(
-    k_cache: jax.Array,  # (B, T, kvd)
-    v_cache: jax.Array,
-    new_k: jax.Array,  # (B, 1, kvd)
-    new_v: jax.Array,
-    pos: jax.Array,  # scalar int32
-) -> Tuple[jax.Array, jax.Array]:
-    """Unbounded-cache baseline: write the token row at index ``pos``.
-    Functional update (returns new arrays); jit/scan-safe."""
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, new_k, pos, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, new_v, pos, axis=1)
-    return k_cache, v_cache
-
-
-def ring_insert(
-    k_cache: jax.Array,  # (B, W, kvd)
-    v_cache: jax.Array,
-    new_k: jax.Array,
-    new_v: jax.Array,
-    pos: jax.Array,
-) -> Tuple[jax.Array, jax.Array]:
-    """Sliding-window baseline: write into ring slot ``pos % W`` (evicting
-    the token W steps back).  Functional update; jit/scan-safe."""
-    W = k_cache.shape[1]
-    slot = (pos % W).astype(jnp.int32)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, new_k, slot, axis=1)
-    v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, new_v, slot, axis=1)
-    return k_cache, v_cache
+def put_row(
+    plane: jax.Array,  # (..., B, T, width)
+    row: jax.Array,  # (B, width)
+    t: jax.Array,  # scalar int32 — the time index written
+    layer: Tuple = (),
+) -> jax.Array:
+    """Write every sequence's row at index ``t`` of a contiguous cache, in
+    place: the unbounded cache writes at the token's position, the
+    sliding-window ring at ``pos % W`` (evicting the token W steps back).
+    ``layer`` as for ``insert_token``."""
+    upd = row.reshape((1,) * len(layer) + (row.shape[0], 1, row.shape[-1]))
+    return jax.lax.dynamic_update_slice(plane, upd, (*layer, 0, t, 0))
 
 
 def ring_positions(pos: jax.Array, window: int) -> jax.Array:

@@ -12,8 +12,10 @@ A model with "mla_moe" layers also carries ``caches["moe"]``: the last
 step's ``[held_pairs, experts_hit]`` summed over its expert layers
 (``layers.held_counts``), for the serving engine's counters.
 
-Caches (decode) are PyTrees with leading (n_repeats, ...) dims scanned along
-with the params; see ``repro.cache.paged_kv`` for the AWRP bounded pool.
+Caches (decode) are PyTrees with leading (n_repeats, ...) dims.  The decode
+step's layer scan carries their row planes (``paged_kv.ROW_PLANES``) whole
+and writes each layer's new row in place; the small planes are scanned along
+with the params.  See ``repro.cache.paged_kv`` for the AWRP bounded pool.
 """
 
 from __future__ import annotations
@@ -575,9 +577,10 @@ def decode_caches(cfg, batch: int, max_len: int, *, kv_mode: str = "full",
 
 def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
                   win_positions, kv_mode: str, fused: bool = False,
-                  mesh=None):
+                  mesh=None, layer: Tuple = ()):
     """One block of one decode step: (x, new cache, expert counts or
-    None)."""
+    None).  ``layer`` picks the block's rows when the cache's row planes
+    are the layer stack (``paged_kv.insert_token``)."""
     B = x.shape[0]
     if kind == "mamba":
         h = L.rmsnorm(x, p["ln1"], cfg.norm_eps)
@@ -590,13 +593,16 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
         if fused:
             raise ValueError("the fused policy-attention kernel serves K/V "
                              "pools only; MLA's latent pool runs unfused")
-        return _decode_mla_block(kind, p, x, h, cfg, cache, pos, kv_mode)
+        return _decode_mla_block(kind, p, x, h, cfg, cache, pos, kv_mode,
+                                 layer)
     nk, nv = L.decode_kv_row(p, h, cfg, position=pos)
     if kind == "local":
-        k, v = paged_kv.ring_insert(cache["k"], cache["v"], nk, nv, pos)
+        slot = pos % cfg.sliding_window
+        k = paged_kv.put_row(cache["k"], nk[:, 0], slot, layer)
+        v = paged_kv.put_row(cache["v"], nv[:, 0], slot, layer)
         kv_pos = jnp.broadcast_to(win_positions[None], (B, win_positions.shape[0]))
-        attn_out, _ = L.decode_attend(p, h, cfg, position=pos, k_cache=k,
-                                      v_cache=v, kv_positions=kv_pos)
+        attn_out, _ = L.decode_attend(p, h, cfg, position=pos, k_cache=k[layer],
+                                      v_cache=v[layer], kv_positions=kv_pos)
         new_cache = {"k": k, "v": v}
     elif kv_mode == "paged":
         adaptive = cfg.kv_policy in paged_kv.TRUE_ADAPTIVE_KV
@@ -610,26 +616,27 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
                                               cfg.bounded_kv_pages)
                 out, _, new_cache = paged_kv.fused_adaptive_decode_step(
                     cache, q, nk[:, 0], nv[:, 0], pos, cfg.page_size, core,
-                    mesh=mesh)
+                    layer=layer, mesh=mesh)
             else:
                 out, _, new_cache = paged_kv.fused_decode_step(
                     cache, q, nk[:, 0], nv[:, 0], pos, cfg.page_size,
-                    cfg.kv_policy, mesh=mesh)
+                    cfg.kv_policy, layer=layer, mesh=mesh)
             attn_out = L.decode_project_out(p, out.astype(x.dtype), cfg)
         else:
             if adaptive:
                 core = paged_kv.adaptive_core(cfg.kv_policy, B,
                                               cfg.bounded_kv_pages)
                 apool = paged_kv.adaptive_insert_token(
-                    cache, nk[:, 0], nv[:, 0], pos, cfg.page_size, core)
+                    cache, nk[:, 0], nv[:, 0], pos, cfg.page_size, core,
+                    layer=layer)
                 pool = apool.pool
             else:
                 pool = paged_kv.insert_token(cache, nk[:, 0], nv[:, 0], pos,
                                              cfg.page_size,
-                                             policy=cfg.kv_policy)
+                                             policy=cfg.kv_policy, layer=layer)
             Ppool, page = pool.f.shape[1], cfg.page_size
-            kflat = pool.k.reshape(B, Ppool * page, -1)
-            vflat = pool.v.reshape(B, Ppool * page, -1)
+            kflat = pool.k[layer].reshape(B, Ppool * page, -1)
+            vflat = pool.v[layer].reshape(B, Ppool * page, -1)
             kv_pos = paged_kv.kv_positions(pool, pos, page)
             attn_out, mass = L.decode_attend(p, h, cfg, position=pos,
                                              k_cache=kflat, v_cache=vflat,
@@ -640,12 +647,13 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
             else:
                 new_cache = paged_kv.score_update(pool, mass, page)
     else:  # full
-        k, v = paged_kv.full_cache_insert(cache["k"], cache["v"], nk, nv, pos)
-        T = k.shape[1]
+        k = paged_kv.put_row(cache["k"], nk[:, 0], pos, layer)
+        v = paged_kv.put_row(cache["v"], nv[:, 0], pos, layer)
+        T = k.shape[-2]
         t = jnp.arange(T, dtype=jnp.int32)
         kv_pos = jnp.broadcast_to(jnp.where(t <= pos, t, -1)[None], (B, T))
-        attn_out, _ = L.decode_attend(p, h, cfg, position=pos, k_cache=k,
-                                      v_cache=v, kv_positions=kv_pos)
+        attn_out, _ = L.decode_attend(p, h, cfg, position=pos, k_cache=k[layer],
+                                      v_cache=v[layer], kv_positions=kv_pos)
         new_cache = {"k": k, "v": v}
     x = x + attn_out
     h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -654,7 +662,7 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
 
 
 def _decode_mla_block(kind: str, p: Params, x, h, cfg, cache, pos,
-                      kv_mode: str):
+                      kv_mode: str, layer: Tuple):
     """An MLA block's decode step: the token's latent row into the cache
     (the latent pool, evicting by ``cfg.kv_policy``, or the full cache),
     absorbed attention over the rows, then the MLP or the expert layer."""
@@ -663,19 +671,18 @@ def _decode_mla_block(kind: str, p: Params, x, h, cfg, cache, pos,
     if kv_mode == "paged":
         page = cfg.page_size
         pool = paged_kv.insert_latent(cache, row, pos, page,
-                                      policy=cfg.kv_policy)
-        rows = pool.c.reshape(B, -1, pool.c.shape[-1])
+                                      policy=cfg.kv_policy, layer=layer)
+        rows = pool.c[layer].reshape(B, -1, pool.c.shape[-1])
         kv_pos = paged_kv.kv_positions(pool, pos, page)
         attn_out, mass = L.mla_decode_attend(p, h, cfg, position=pos,
                                              cache=rows, kv_positions=kv_pos)
         new_cache = paged_kv.score_update(pool, mass, page)
     else:
-        c = jax.lax.dynamic_update_slice_in_dim(cache["c"], row[:, None], pos,
-                                                axis=1)
-        t = jnp.arange(c.shape[1], dtype=jnp.int32)
-        kv_pos = jnp.broadcast_to(jnp.where(t <= pos, t, -1)[None], (B, c.shape[1]))
-        attn_out, _ = L.mla_decode_attend(p, h, cfg, position=pos, cache=c,
-                                          kv_positions=kv_pos)
+        c = paged_kv.put_row(cache["c"], row, pos, layer)
+        t = jnp.arange(c.shape[-2], dtype=jnp.int32)
+        kv_pos = jnp.broadcast_to(jnp.where(t <= pos, t, -1)[None], (B, t.shape[0]))
+        attn_out, _ = L.mla_decode_attend(p, h, cfg, position=pos,
+                                          cache=c[layer], kv_positions=kv_pos)
         new_cache = {"c": c}
     x = x + attn_out
     h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -702,7 +709,8 @@ def _encdec_decode(params, cfg, token, caches):
         cp = {k[6:]: v for k, v in p.items() if k.startswith("cross_")}
         a = L.rmsnorm(h, p["ln1"], cfg.norm_eps)
         nk, nv = L.decode_kv_row(sp, a, cfg, position=pos, use_rope=False)
-        k, v = paged_kv.full_cache_insert(c["k"], c["v"], nk, nv, pos)
+        k = paged_kv.put_row(c["k"], nk[:, 0], pos)
+        v = paged_kv.put_row(c["v"], nv[:, 0], pos)
         T = k.shape[1]
         t = jnp.arange(T, dtype=jnp.int32)
         kv_pos = jnp.broadcast_to(jnp.where(t <= pos, t, -1)[None], (B, T))
@@ -743,7 +751,10 @@ def decode_step(params: Params, cfg, token: jax.Array, caches,
 
     lead, unit, n_rep, tail = scan_plan(cfg)
     stacked_params = {p: params[p] for p, k in unit if k != "shared_attn"}
-    stacked_caches = {p: caches["blocks"][p] for p, k in unit}
+    # the row planes ride the scan's carry whole, each layer writing only its
+    # new row into them; the small planes are scanned per layer
+    rows, rest, how = paged_kv.split_rows(
+        {p: caches["blocks"][p] for p, k in unit})
     new_blocks, counts = {}, None
     for pname, kind in lead:
         x, new_blocks[pname], c = _decode_block(
@@ -752,22 +763,25 @@ def decode_step(params: Params, cfg, token: jax.Array, caches,
         counts = _add_counts(counts, c)
 
     def body(carry, xs):
-        h = carry
-        pslices, cslices = xs
+        h, rows = carry
+        pslices, rest, layer = xs
+        cslices = paged_kv.merge_rows(rows, rest, how)
         new_caches, unit_counts = {}, None
         for pname, kind in unit:
             prm = params["shared_attn"] if kind == "shared_attn" else pslices[pname]
             h, new_caches[pname], c = _decode_block(
                 kind, prm, h, cfg, cslices[pname], pos, win_positions,
-                kv_mode, fused, mesh)
+                kv_mode, fused, mesh, (layer,))
             unit_counts = _add_counts(unit_counts, c)
-        return h, (new_caches, unit_counts)
+        rows, rest, _ = paged_kv.split_rows(new_caches)
+        return (h, rows), (rest, unit_counts)
 
-    x, (new_stacked, unit_counts) = jax.lax.scan(
-        body, x, (stacked_params, stacked_caches))
+    (x, rows), (rest, unit_counts) = jax.lax.scan(
+        body, (x, rows),
+        (stacked_params, rest, jnp.arange(n_rep, dtype=jnp.int32)))
     if unit_counts is not None:
         counts = _add_counts(counts, unit_counts.sum(0))
-    new_blocks.update(new_stacked)
+    new_blocks.update(paged_kv.merge_rows(rows, rest, how))
     for pname, kind in tail:
         x, new_blocks[pname], c = _decode_block(
             kind, params[pname], x, cfg, caches["blocks"][pname], pos,

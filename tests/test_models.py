@@ -7,8 +7,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs.base import ARCH_IDS, SHAPES, load_smoke_config
+from repro.cache import paged_kv
+from repro.configs.base import ARCH_IDS, SHAPES, ModelConfig, load_smoke_config
 from repro.models import model as M
+from repro.serve.engine import Request, ServeEngine
 
 jax.config.update("jax_default_matmul_precision", "float32")
 
@@ -186,3 +188,109 @@ def test_awrp_victim_matches_host_oracle():
             jnp.ones((1, P), bool), jnp.zeros((1, P), bool),
         )
         assert int(got[0]) == expect
+
+
+# ---------------------------------------------------------------------------
+# the decode step's layer scan carries the row planes and writes by row
+# ---------------------------------------------------------------------------
+
+_MLA_CFG = ModelConfig(
+    name="mla-rows-test", family="moe", n_layers=3, d_model=64, n_heads=4,
+    n_kv_heads=4, d_ff=96, vocab=256, rope_theta=50_000.0, norm_eps=1e-5,
+    lead=("mla",), pattern=("mla_moe",), n_repeats=2, kv_lora_rank=32,
+    qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16, n_experts=16,
+    top_k=3, expert_ff=24, shared_ff=48, experts_held=4, expert_offset=0,
+    router_scoring="sigmoid", routed_scaling=2.446, page_size=4,
+    bounded_kv_pages=3, remat="none")
+
+
+def _row_case(name):
+    if name == "mla":
+        return _MLA_CFG
+    cfg = dataclasses.replace(load_smoke_config("smollm_360m"), n_layers=3,
+                              bounded_kv_pages=3, page_size=4)
+    return dataclasses.replace(cfg, kv_policy=name)
+
+
+def _layer_by_layer_step(params, cfg, token, caches):
+    """The reference: ``decode_step`` with each layer's whole cache sliced
+    out of the stack as a scan input and written back whole as a scan
+    output, every block run on its own layer's pool (``insert_*`` and
+    ``score_update`` on that slice)."""
+    pos = caches["pos"]
+    lead, unit, _, _ = M.scan_plan(cfg)
+    blocks, counts = dict(caches["blocks"]), None
+    x = jnp.take(params["embed"], token, axis=0).astype(jnp.dtype(cfg.dtype))
+
+    def block(kind, p, h, cache):
+        return M._decode_block(kind, p, h, cfg, cache, pos, None, "paged")
+
+    for name, kind in lead:
+        x, blocks[name], c = block(kind, params[name], x, blocks[name])
+        counts = M._add_counts(counts, c)
+
+    def body(h, xs):
+        p, cs = xs
+        new, unit_counts = {}, None
+        for name, kind in unit:
+            h, new[name], c = block(kind, p[name], h, cs[name])
+            unit_counts = M._add_counts(unit_counts, c)
+        return h, (new, unit_counts)
+
+    x, (new, unit_counts) = jax.lax.scan(
+        body, x, ({n: params[n] for n, _ in unit},
+                  {n: blocks[n] for n, _ in unit}))
+    blocks.update(new)
+    out = {"pos": pos + 1, "blocks": blocks}
+    if unit_counts is not None:
+        out["moe"] = M._add_counts(counts, unit_counts.sum(0))
+    return M.logits_from_hidden(params, cfg, x), out
+
+
+@pytest.mark.parametrize("case", ["awrp", "arc_adaptive", "mla"])
+def test_carried_row_planes_decode_bit_identical_to_layer_by_layer(case):
+    """The pool full at decode start (3 pages of 4), 10 greedy steps over
+    three page boundaries, each evicting in every layer: tokens, logits and
+    every plane of every layer's pool equal the layer-by-layer reference
+    bit for bit."""
+    cfg = _row_case(case)
+    params = M.init_params(cfg, jax.random.PRNGKey(5))
+    tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 0, cfg.vocab)
+    logits, caches = M.prefill(params, cfg, {"tokens": tokens}, max_len=32,
+                               kv_mode="paged")
+    carried = jax.jit(lambda t, c: M.decode_step(params, cfg, t, c,
+                                                 kv_mode="paged"))
+    reference = jax.jit(lambda t, c: _layer_by_layer_step(params, cfg, t, c))
+    tok = jnp.argmax(logits[:, :, : cfg.vocab], -1).astype(jnp.int32)
+    got, want = (tok, caches), (tok, caches)
+    evicted = 0
+    for _ in range(10):
+        (lg, c), (lw, w) = carried(*got), reference(*want)
+        np.testing.assert_array_equal(np.asarray(lg), np.asarray(lw))
+        jax.tree.map(lambda a, b: np.testing.assert_array_equal(
+            np.asarray(a), np.asarray(b)), c, w)
+        evicted += int(paged_kv.step_counts(got[1], c)[1])
+        nxt = jnp.argmax(lg[:, :, : cfg.vocab], -1).astype(jnp.int32)
+        got, want = (nxt, c), (nxt, w)
+    # every paged layer evicts one page a sequence at pos 12, 16 and 20
+    assert evicted == 3 * 2 * cfg.n_layers
+
+
+def test_decode_loop_writes_the_stacked_pool_in_place():
+    """The engine's compiled decode loop makes no copy of a whole stacked
+    K/V plane: each layer reads and writes its rows in the layer scan's
+    carried stack, which the donated loop carry aliases."""
+    cfg = dataclasses.replace(load_smoke_config("smollm_360m"), n_layers=4,
+                              bounded_kv_pages=4, page_size=8)
+    params = M.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, max_len=64, kv_mode="paged")
+    eng.generate([Request(0, list(range(1, 33)), max_new_tokens=8)])
+    k = jax.eval_shape(lambda: M.decode_caches(cfg, 1, 64, kv_mode="paged"))
+    k = k["blocks"]["u0"].k
+    shape = ({"bfloat16": "bf16", "float32": "f32"}[k.dtype.name]
+             + "[" + ",".join(map(str, k.shape)) + "]")
+    text = eng._loop_sentinel.compiled_text()
+    assert f"= {shape}" in text  # the loop carries the stacked plane
+    copies = [ln for ln in text.splitlines()
+              if f"= {shape}" in ln and " copy(" in ln]
+    assert not copies, copies
