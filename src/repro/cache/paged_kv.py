@@ -87,6 +87,13 @@ def merge_rows(rows, rest, how):
     return treedef.unflatten([next(rows) if r else next(rest) for r in is_row])
 
 
+def layer_rows(plane: jax.Array, layer: Tuple = ()) -> jax.Array:
+    """(B, T, width): one layer's rows of a row plane, a pool's pages laid
+    end to end (T = pages * page) or a contiguous cache's T rows."""
+    rows = plane[layer]
+    return rows.reshape(rows.shape[0], -1, rows.shape[-1])
+
+
 def write_token(planes, rows, layer, need_alloc, slot, within):
     """Write each sequence's new token row into a pool's row planes, in
     place: ``rows[i]`` (B, width) goes to ``planes[i][*layer, b, slot[b],
@@ -750,8 +757,8 @@ def fused_decode_step(
 
     B, P = pool.f.shape
     KVH, G, hd = q.shape[1:]
-    kp = pool.k[layer].reshape(B, P, page_size, KVH, hd)
-    vp = pool.v[layer].reshape(B, P, page_size, KVH, hd)
+    kp = layer_rows(pool.k, layer).reshape(B, P, page_size, KVH, hd)
+    vp = layer_rows(pool.v, layer).reshape(B, P, page_size, KVH, hd)
     nk = new_k.reshape(B, KVH, hd)
     nv = new_v.reshape(B, KVH, hd)
     pos = jnp.asarray(pos, jnp.int32)
@@ -795,8 +802,8 @@ def fused_adaptive_decode_step(
     B, P = pool.f.shape
     KVH, G, hd = q.shape[1:]
     L = pstate.blocks.shape[-1]
-    kp = pool.k[layer].reshape(B, P, page_size, KVH, hd)
-    vp = pool.v[layer].reshape(B, P, page_size, KVH, hd)
+    kp = layer_rows(pool.k, layer).reshape(B, P, page_size, KVH, hd)
+    vp = layer_rows(pool.v, layer).reshape(B, P, page_size, KVH, hd)
     nk = new_k.reshape(B, KVH, hd)
     nv = new_v.reshape(B, KVH, hd)
     pos = jnp.asarray(pos, jnp.int32)
@@ -835,11 +842,17 @@ def put_row(
     layer: Tuple = (),
 ) -> jax.Array:
     """Write every sequence's row at index ``t`` of a contiguous cache, in
-    place: the unbounded cache writes at the token's position, the
-    sliding-window ring at ``pos % W`` (evicting the token W steps back).
-    ``layer`` as for ``insert_token``."""
+    place; the unbounded cache writes at the token's position.  ``layer``
+    as for ``insert_token``."""
     upd = row.reshape((1,) * len(layer) + (row.shape[0], 1, row.shape[-1]))
     return jax.lax.dynamic_update_slice(plane, upd, (*layer, 0, t, 0))
+
+
+def ring_put_row(plane: jax.Array, row: jax.Array, pos: jax.Array,
+                 layer: Tuple = ()) -> jax.Array:
+    """``put_row`` into a sliding-window ring: slot ``pos % W``, W the
+    ring's width (evicting the token W steps back)."""
+    return put_row(plane, row, pos % plane.shape[-2], layer)
 
 
 def ring_positions(pos: jax.Array, window: int) -> jax.Array:

@@ -597,12 +597,12 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
                                  layer)
     nk, nv = L.decode_kv_row(p, h, cfg, position=pos)
     if kind == "local":
-        slot = pos % cfg.sliding_window
-        k = paged_kv.put_row(cache["k"], nk[:, 0], slot, layer)
-        v = paged_kv.put_row(cache["v"], nv[:, 0], slot, layer)
+        k = paged_kv.ring_put_row(cache["k"], nk[:, 0], pos, layer)
+        v = paged_kv.ring_put_row(cache["v"], nv[:, 0], pos, layer)
         kv_pos = jnp.broadcast_to(win_positions[None], (B, win_positions.shape[0]))
-        attn_out, _ = L.decode_attend(p, h, cfg, position=pos, k_cache=k[layer],
-                                      v_cache=v[layer], kv_positions=kv_pos)
+        attn_out, _ = L.decode_attend(
+            p, h, cfg, position=pos, k_cache=paged_kv.layer_rows(k, layer),
+            v_cache=paged_kv.layer_rows(v, layer), kv_positions=kv_pos)
         new_cache = {"k": k, "v": v}
     elif kv_mode == "paged":
         adaptive = cfg.kv_policy in paged_kv.TRUE_ADAPTIVE_KV
@@ -634,13 +634,13 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
                 pool = paged_kv.insert_token(cache, nk[:, 0], nv[:, 0], pos,
                                              cfg.page_size,
                                              policy=cfg.kv_policy, layer=layer)
-            Ppool, page = pool.f.shape[1], cfg.page_size
-            kflat = pool.k[layer].reshape(B, Ppool * page, -1)
-            vflat = pool.v[layer].reshape(B, Ppool * page, -1)
+            page = cfg.page_size
             kv_pos = paged_kv.kv_positions(pool, pos, page)
-            attn_out, mass = L.decode_attend(p, h, cfg, position=pos,
-                                             k_cache=kflat, v_cache=vflat,
-                                             kv_positions=kv_pos)
+            attn_out, mass = L.decode_attend(
+                p, h, cfg, position=pos,
+                k_cache=paged_kv.layer_rows(pool.k, layer),
+                v_cache=paged_kv.layer_rows(pool.v, layer),
+                kv_positions=kv_pos)
             if adaptive:
                 new_cache = paged_kv.adaptive_score_update(apool, mass, page,
                                                            core)
@@ -652,8 +652,9 @@ def _decode_block(kind: str, p: Params, x: jax.Array, cfg, cache, pos,
         T = k.shape[-2]
         t = jnp.arange(T, dtype=jnp.int32)
         kv_pos = jnp.broadcast_to(jnp.where(t <= pos, t, -1)[None], (B, T))
-        attn_out, _ = L.decode_attend(p, h, cfg, position=pos, k_cache=k[layer],
-                                      v_cache=v[layer], kv_positions=kv_pos)
+        attn_out, _ = L.decode_attend(
+            p, h, cfg, position=pos, k_cache=paged_kv.layer_rows(k, layer),
+            v_cache=paged_kv.layer_rows(v, layer), kv_positions=kv_pos)
         new_cache = {"k": k, "v": v}
     x = x + attn_out
     h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -672,7 +673,7 @@ def _decode_mla_block(kind: str, p: Params, x, h, cfg, cache, pos,
         page = cfg.page_size
         pool = paged_kv.insert_latent(cache, row, pos, page,
                                       policy=cfg.kv_policy, layer=layer)
-        rows = pool.c[layer].reshape(B, -1, pool.c.shape[-1])
+        rows = paged_kv.layer_rows(pool.c, layer)
         kv_pos = paged_kv.kv_positions(pool, pos, page)
         attn_out, mass = L.mla_decode_attend(p, h, cfg, position=pos,
                                              cache=rows, kv_positions=kv_pos)
@@ -682,7 +683,8 @@ def _decode_mla_block(kind: str, p: Params, x, h, cfg, cache, pos,
         t = jnp.arange(c.shape[-2], dtype=jnp.int32)
         kv_pos = jnp.broadcast_to(jnp.where(t <= pos, t, -1)[None], (B, t.shape[0]))
         attn_out, _ = L.mla_decode_attend(p, h, cfg, position=pos,
-                                          cache=c[layer], kv_positions=kv_pos)
+                                          cache=paged_kv.layer_rows(c, layer),
+                                          kv_positions=kv_pos)
         new_cache = {"c": c}
     x = x + attn_out
     h2 = L.rmsnorm(x, p["ln2"], cfg.norm_eps)

@@ -247,12 +247,41 @@ def _layer_by_layer_step(params, cfg, token, caches):
     return M.logits_from_hidden(params, cfg, x), out
 
 
+def _assert_rows_written(old, new, page):
+    """The row rule in numpy, apart from the row writer: on a page boundary
+    the page the pool's bookkeeping opened (its open slot, starting at this
+    token) is zeroed, then the token's row, which is not all zero, goes to
+    its place in that page; every other row of every layer stays as it
+    was."""
+    pos = int(old["pos"])
+    within = pos % page
+    for name, node in old["blocks"].items():
+        was, now = getattr(node, "pool", node), new["blocks"][name]
+        now = getattr(now, "pool", now)  # an adaptive pool wraps a classic one
+        slot = np.asarray(now.open_slot)  # (layers, B) or (B,)
+        start = np.asarray(now.page_start)
+        for plane in ("k", "v", "c"):
+            if not hasattr(was, plane):
+                continue
+            got = np.asarray(getattr(now, plane), np.float32)
+            want = np.asarray(getattr(was, plane), np.float32).copy()
+            for at in np.ndindex(*slot.shape):
+                page_at = (*at, slot[at])
+                assert start[page_at] == pos - within
+                if within == 0:
+                    want[page_at] = 0
+                assert got[(*page_at, within)].any()
+                want[(*page_at, within)] = got[(*page_at, within)]
+            np.testing.assert_array_equal(got, want)
+
+
 @pytest.mark.parametrize("case", ["awrp", "arc_adaptive", "mla"])
 def test_carried_row_planes_decode_bit_identical_to_layer_by_layer(case):
     """The pool full at decode start (3 pages of 4), 10 greedy steps over
     three page boundaries, each evicting in every layer: tokens, logits and
     every plane of every layer's pool equal the layer-by-layer reference
-    bit for bit."""
+    bit for bit, and each step's row writes follow the row rule checked
+    apart from the writer both sides share (``_assert_rows_written``)."""
     cfg = _row_case(case)
     params = M.init_params(cfg, jax.random.PRNGKey(5))
     tokens = jax.random.randint(jax.random.PRNGKey(6), (2, 12), 0, cfg.vocab)
@@ -269,6 +298,7 @@ def test_carried_row_planes_decode_bit_identical_to_layer_by_layer(case):
         np.testing.assert_array_equal(np.asarray(lg), np.asarray(lw))
         jax.tree.map(lambda a, b: np.testing.assert_array_equal(
             np.asarray(a), np.asarray(b)), c, w)
+        _assert_rows_written(got[1], c, cfg.page_size)
         evicted += int(paged_kv.step_counts(got[1], c)[1])
         nxt = jnp.argmax(lg[:, :, : cfg.vocab], -1).astype(jnp.int32)
         got, want = (nxt, c), (nxt, w)
