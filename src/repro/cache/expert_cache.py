@@ -8,8 +8,8 @@ cost we count).  This is the paper's policy applied to multi-gigabyte cache
 the request mix), which is AWRP's exact design point.
 
 ``simulate_router_trace`` reuses the core simulator so AWRP/LRU/FIFO/CAR/ARC
-numbers are apples-to-apples with the paper's Table 1 methodology; the bench
-(benchmarks/expert_cache_bench.py) reports miss-rate == transfer volume.
+numbers are apples-to-apples with the paper's Table 1 methodology; the study
+(studies/expert_cache_bench.py) reports miss-rate == transfer volume.
 
 ``ExpertCacheRuntime`` has two execution paths behind one accounting
 surface:

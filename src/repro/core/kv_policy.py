@@ -4,7 +4,7 @@ its baselines, applied to the serving cache).
 ``page_victim`` is the single decision point used by the classic paged pool:
 AWRP is the paper's eq. (1); LRU/FIFO/LFU are the baselines the paper
 compares against, re-expressed on page metadata so the serving ablation
-(benchmarks/serve_policy_bench.py) is apples-to-apples.  ``arc`` and ``car``
+(studies/serve_policy_bench.py) is apples-to-apples.  ``arc`` and ``car``
 are stateless two-segment approximations of the adaptive policies on the
 same metadata (DESIGN.md §2): pages referenced at most once since insertion
 form the T1-analog (evicted first), multiply-referenced pages the T2-analog;

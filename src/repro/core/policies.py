@@ -111,8 +111,8 @@ class AWRP(ReplacementPolicy):
     def __init__(self, capacity: int, alpha: float = 1.0, beta: float = 1.0):
         """alpha/beta generalize eq. (1) to W = F^alpha / (N-R)^beta — the
         paper's §5 future-work direction ("additional parameters and
-        factors"); (1, 1) is the paper's exact policy. Benchmarked in
-        benchmarks/awrp_ablation.py."""
+        factors"); (1, 1) is the paper's exact policy. Studied in
+        studies/awrp_ablation.py."""
         super().__init__(capacity)
         self.alpha = float(alpha)
         self.beta = float(beta)
@@ -622,7 +622,7 @@ def make_policy(name: str, capacity: int, **kw) -> ReplacementPolicy:
 
 # ---------------------------------------------------------------------------
 # A-AWRP — adaptive alpha/beta (beyond paper; motivated by the ablation in
-# benchmarks/awrp_ablation.py: frequency-leaning weights win on zipf-like
+# studies/awrp_ablation.py: frequency-leaning weights win on zipf-like
 # traces, recency-leaning on loop traces, eq. (1) is the best fixed point)
 # ---------------------------------------------------------------------------
 

@@ -27,8 +27,9 @@ policy arithmetic is literally the shared step functions traced at rows=1
 (all their reductions are row-local — the batched call computes the same
 per-row result), and the attention mass recurrence is
 ``paged_attn.flash_page_step``, shared with the unfused kernel, so the
-reference threshold sees bitwise-equal inputs.  Hard-gated in tests/test_policy_attn.py and
-benchmarks/policy_attn_bench.py.
+reference threshold sees bitwise-equal inputs.  Hard-gated in
+tests/test_policy_attn.py, which also holds the fused step's jaxpr to fewer
+equations than the unfused chain's.
 
 The pool K/V arrays stay read-only here (writing them through the kernel
 would force a full copy-through of the pool every step); the caller applies

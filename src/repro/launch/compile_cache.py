@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache for the entry points.
 
-Called by ``chip_smoke.py``, ``repro.launch.serve`` and
-``benchmarks/run.py`` at start-up, never on library import.  Where
+Called by ``chip_smoke.py``, ``repro.launch.serve`` and the benchmark's
+harness at start-up, never on library import.  Where
 ``JAX_COMPILATION_CACHE_DIR`` is set JAX reads it itself and nothing is set
 here; otherwise the cache goes to ``<repo>/.jax_cache`` — a fixed path,
 because the directory is part of what a later run must find again.

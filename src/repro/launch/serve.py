@@ -63,10 +63,6 @@ def main(argv=None):
     ap.add_argument("--auto-rebalance", action="store_true",
                     help="move quota lanes to pressured tenants from the "
                     "coldest (AWRP tenant ranking)")
-    ap.add_argument("--host-loop", action="store_true",
-                    help="use the host-orchestrated per-step decode loop "
-                    "instead of the default fully-jitted donated-buffer "
-                    "loop (DESIGN.md §9) — the serve_loop bench baseline")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="export the final telemetry snapshot: writes "
                     "PATH.prom (Prometheus text exposition) and appends one "
@@ -116,7 +112,7 @@ def main(argv=None):
     engine = ServeEngine(cfg, params, max_len=args.max_len,
                          kv_mode=args.kv_mode, tenants=tenants,
                          auto_rebalance=args.auto_rebalance,
-                         jit_loop=not args.host_loop, fused=args.fused,
+                         fused=args.fused,
                          decision_trace=args.decision_trace,
                          profile_dir=args.profile_dir,
                          profile_every=args.profile_every,
@@ -163,9 +159,8 @@ def main(argv=None):
             results.update(engine.generate([r]))
     dt = time.time() - t0
     total_tokens = sum(len(r.tokens) for r in results.values())
-    loop = "host" if args.host_loop else "jit"
     print(f"arch={cfg.name} kv_mode={args.kv_mode} policy={args.kv_policy} "
-          f"loop={loop} fused={args.fused}")
+          f"fused={args.fused}")
     print(f"{len(reqs)} requests, {total_tokens} tokens in {dt:.2f}s "
           f"({total_tokens/dt:.1f} tok/s host-side)")
     if args.decision_trace:

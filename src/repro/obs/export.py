@@ -19,8 +19,7 @@ Both take the flat namespaced snapshot dict ``Registry.snapshot()`` (and
   log a scrape-less deployment tails.
 
 Wired into ``launch/serve.py --metrics-out`` (writes ``<path>.prom`` and
-appends ``<path>.jsonl``); ``benchmarks/obs_bench.py`` emits the sample
-snapshot the CI bench-smoke job uploads as an artifact.
+appends ``<path>.jsonl``).
 """
 
 from __future__ import annotations

@@ -14,10 +14,9 @@ same single pull).
 Device metric planes for the decode loop (``loop_planes`` /
 ``loop_update``) follow the ``RowCounters`` idiom: a small int32 pytree
 carried through the jitted scan (donated alongside the KV caches) and
-advanced by the SAME jitted update on the host-orchestrated path, so the
-planes are bit-identical between ``jit_loop=True`` and the host loop —
-integer adds and scatter-adds have no reassociation freedom
-(tests/test_obs.py pins it).
+advanced by integer adds and scatter-adds only, which have no
+reassociation freedom, so they count the served tokens exactly and are
+bit-identical across row meshes (tests/test_obs.py pins both).
 
 ``safe_ratio`` is the one guarded hit-ratio division every surface uses
 (prefix cache, expert cache, simulator, tenancy) — the zero-access
@@ -161,9 +160,8 @@ class Registry:
 
 def loop_planes(bins: int = HIST_BINS) -> Dict[str, jax.Array]:
     """Fresh all-zero decode-loop metric planes: sampled-step and token
-    counters (0-d int32) plus a ``(bins,)`` token-id histogram.  Carried
-    through the jitted decode scan (donated with the KV caches) or folded
-    per step by the host loop — same jitted update either way."""
+    counters (0-d int32) plus a ``(bins,)`` token-id histogram, carried
+    through the jitted decode scan (donated with the KV caches)."""
     return {
         "steps": jnp.int32(0),
         "tokens": jnp.int32(0),

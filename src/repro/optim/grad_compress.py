@@ -11,7 +11,6 @@ Two pieces:
     contexts: each shard quantizes to int8, the ALL-GATHER moves int8 bytes
     (4x fewer collective bytes, visible in the HLO and counted by the
     roofline's collective term), and the sum happens locally in fp32.
-    Benchmarked in ``benchmarks/grad_compress_bench.py``.
 """
 
 from __future__ import annotations

@@ -25,19 +25,18 @@ configs; the same jitted functions are what the dry-run lowers for the
     is built through the unified policy factory and reports a uniform
     ``telemetry()`` dict under a namespaced key (``prefix/...``,
     ``kv/...``, ``expert/...``) — see ``ServeEngine.telemetry``;
-  * fully-jitted decode loop: by default the whole decode loop (decode
-    step + sampling + PRNG chain) is ONE jitted program per ``steps``
-    bucket (temperature is traced) with the KV caches and PRNG key donated in
-    (``donate_argnums`` — XLA reuses the buffers in place), and
+  * fully-jitted decode loop: the whole decode loop (decode step +
+    sampling + PRNG chain) is ONE jitted program per ``steps`` bucket
+    (temperature is traced) with the KV caches, PRNG key and loop-metric
+    planes donated in (``donate_argnums`` — XLA reuses the buffers in
+    place), and
     multi-tenant admission runs as one jitted batch scan on the device
-    pressure plane; ``jit_loop=False`` restores the host-orchestrated
-    per-step loop (the measured baseline) — DESIGN.md §9.
+    pressure plane — DESIGN.md §9.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from typing import Dict, List, Optional
 
@@ -63,6 +62,11 @@ from repro.serve.tenancy import (
     AdmissionController,
     TenantPrefixCache,
 )
+
+#: the single-tenant prompt cache's entries, and the policy that evicts from
+#: it (or from each tenant's row of the multi-tenant prompt cache)
+PREFIX_ENTRIES = 8
+PREFIX_POLICY = "awrp"
 
 
 @dataclasses.dataclass
@@ -112,20 +116,15 @@ def _kv_step(old_caches, new_caches) -> jax.Array:
 class ServeEngine:
     """Continuous-batching serving engine over AWRP-managed caches.
 
-    Two decode-loop modes (DESIGN.md §9):
-
-    * ``jit_loop=True`` (default) — ONE jitted program per ``steps``
-      bucket (temperature traced) runs the whole decode loop on device (``lax.scan`` of
-      decode+sample), with the KV caches and the PRNG key DONATED into it
-      (``jax.jit(..., donate_argnums=...)``): XLA reuses the cache buffers
-      in place, and host code only marshals inputs/outputs.  Admission for
-      multi-tenant engines runs as one jitted batch scan
-      (``AdmissionController.decide_batch``) on the device pressure plane.
-    * ``jit_loop=False`` — the host-orchestrated per-step loop (one jitted
-      decode step per token, sampling and admission on host).  Kept as the
-      measured baseline for ``benchmarks/serve_loop_bench.py``; token
-      streams across the two modes agree in sampling LOGIC but are not
-      asserted bit-identical (scan-compiled vs per-call numerics).
+    Decoding is ONE jitted program per ``steps`` bucket (temperature
+    traced, DESIGN.md §9): a ``lax.scan`` of decode + sample on device,
+    with the KV caches, the PRNG key and the loop-metric planes DONATED
+    into it (``jax.jit(..., donate_argnums=...)``), so XLA reuses their
+    buffers in place and host code only marshals inputs and outputs.
+    Each step also leaves the scan with its paged-KV (and expert) counts,
+    which the host sums.  Admission for multi-tenant engines runs as one
+    jitted batch scan (``AdmissionController.decide_batch``) on the
+    device pressure plane.
 
     State mutated per ``generate`` call: ``self.key`` (PRNG chain),
     ``self.stats``, the prefix/tenant caches, and (true-adaptive paged
@@ -134,12 +133,10 @@ class ServeEngine:
     snapshotted on insert and on hit (see ``_run_bucket``)."""
 
     def __init__(self, cfg, params, *, max_len: int = 512,
-                 kv_mode: str = "full", prefix_cache_entries: int = 8,
-                 prefix_policy: str = "awrp", expert_cache=None, seed: int = 0,
+                 kv_mode: str = "full", seed: int = 0,
                  tenants: Optional[Dict[str, int]] = None,
                  admission: Optional[AdmissionController] = None,
-                 auto_rebalance: bool = False, jit_loop: bool = True,
-                 mesh=None, fused: bool = False, metrics: bool = True,
+                 auto_rebalance: bool = False, mesh=None, fused: bool = False,
                  decision_trace: int = 0, profile_dir: Optional[str] = None,
                  profile_every: int = 16, profile_phases: bool = False):
         self.cfg = cfg
@@ -164,22 +161,20 @@ class ServeEngine:
                 "events; construct the engine with tenants={...}"
             )
         if self.tenants is None:
-            # prefix_policy may be a name or a prebuilt policy instance —
-            # both resolve through the unified factory inside PrefixCache
-            self.prefix_cache = PrefixCache(prefix_cache_entries, prefix_policy)
+            self.prefix_cache = PrefixCache(PREFIX_ENTRIES, PREFIX_POLICY)
             self.tenant_cache = None
             self.admission = None
         else:
             self.prefix_cache = None
             self.tenant_cache = TenantPrefixCache(
-                self.tenants, prefix_policy, mesh=mesh,
+                self.tenants, PREFIX_POLICY, mesh=mesh,
                 ring_capacity=int(decision_trace),
             )
             self.admission = admission or AdmissionController()
         #: optional ExpertCacheRuntime the model's MoE router reports into
-        self.expert_cache = expert_cache
+        #: (None until a caller assigns one)
+        self.expert_cache = None
         self.key = jax.random.PRNGKey(seed)
-        self.jit_loop = bool(jit_loop)
         # compile/retrace sentinels (obs.profiling, DESIGN.md §12) around
         # every jitted entry point the engine builds: trace counts, cache
         # sizes, trace wall time and jaxpr eqn audits surface under
@@ -187,11 +182,6 @@ class ServeEngine:
         self._prefill = profiling.instrument(
             "prefill",
             lambda p, b: M.prefill(p, cfg, b, max_len=max_len, kv_mode=kv_mode)
-        )
-        self._decode = profiling.instrument(
-            "decode_step",
-            lambda p, t, c: M.decode_step(p, cfg, t, c, kv_mode=kv_mode,
-                                          fused=self.fused, mesh=mesh)
         )
         #: jitted whole-decode-loop programs, one per steps bucket
         #: (temperature is a traced operand — no retrace per temperature);
@@ -209,14 +199,10 @@ class ServeEngine:
         self._kv_sessions: Dict[str, list] = {}
         self._kv_ghost_hits: Dict[str, int] = {}
         # -- observability layer (DESIGN.md §11) ----------------------------
-        #: loop-metric planes carried through the jitted decode loop (or
-        #: folded per step by the host loop — same jitted update, so the
-        #: planes are bit-identical across the modes); None with metrics off
-        self.metrics = bool(metrics)
-        self._planes = loop_planes() if self.metrics else None
-        self._fold = jax.jit(functools.partial(loop_update, vocab=cfg.vocab))
+        #: loop-metric planes carried (donated) through the jitted decode loop
+        self._planes = loop_planes()
         #: paged-KV page hits (paper reference rule) and evictions, summed
-        #: over layers, sequences and decode steps: the decode loops emit
+        #: over layers, sequences and decode steps: the decode loop emits
         #: per-step counts and the host adds them here as Python ints, so
         #: the totals never wrap however long the engine serves
         self._kv_counts = {"hits": 0, "evictions": 0}
@@ -228,7 +214,6 @@ class ServeEngine:
             {ph: {"held_pairs": 0, "experts_hit": 0}
              for ph in ("prefill", "decode")}
             if M.has_expert_counts(cfg) else None)
-        self._kv_step = jax.jit(_kv_step)
         #: host timing spans around the serving sections (prefill / decode /
         #: rebalance / trace_drain) — mounted on the registry like the
         #: caches.  ``profile_phases=True`` turns on the sync discipline:
@@ -280,12 +265,13 @@ class ServeEngine:
     def _get_loop(self, steps: int):
         """The jitted decode-loop program for this ``steps`` bucket: greedy
         first token from the prefill logits, then ``steps - 1`` scanned
-        decode+sample iterations.  ``caches`` and ``key`` are DONATED — the
-        caller must treat the passed-in values as consumed and use only the
-        returned ones (stored prefix payloads are snapshotted around this,
-        see ``_run_bucket``).  ``temperature`` is a TRACED loop operand
-        (``sample_traced``), so only ``steps`` buckets compile — previously
-        every (steps, temperature) pair retraced the whole loop."""
+        decode+sample iterations.  ``caches``, ``key`` and ``planes`` are
+        DONATED — the caller must treat the passed-in values as consumed
+        and use only the returned ones (stored prefix payloads are
+        snapshotted around this, see ``_run_bucket``).  ``temperature`` is
+        a TRACED loop operand (``sample_traced``), so only ``steps``
+        buckets compile — previously every (steps, temperature) pair
+        retraced the whole loop."""
         k = int(steps)
         loop = self._loops.get(k)
         if loop is None:
@@ -297,58 +283,33 @@ class ServeEngine:
         cfg, kv_mode = self.cfg, self.kv_mode
         fused, mesh = self.fused, self.mesh
 
-        if self.metrics:
-            # metrics variant: the loop planes are one more donated carry —
-            # folded after every sampling event (first greedy token
-            # included) by the same jitted `loop_update` the host loop
-            # applies per step, so the planes are bit-identical across the
-            # loop modes (integer adds / scatter-adds only).  The paged-KV
-            # counts leave the scan per step (``_kv_step``)
-            def loop(params, logits, caches, key, temperature, planes):
-                toks = sample(logits[:, -1:], key, temperature=0.0,
-                              vocab=cfg.vocab)
-                planes = loop_update(planes, toks, vocab=cfg.vocab)
-
-                def body(carry, _):
-                    t, c, k, pl = carry
-                    k, sub = jax.random.split(k)
-                    lg, c2 = M.decode_step(params, cfg, t, c, kv_mode=kv_mode,
-                                           fused=fused, mesh=mesh)
-                    t = sample_traced(lg, sub, temperature, vocab=cfg.vocab)
-                    pl = loop_update(pl, t, vocab=cfg.vocab)
-                    return (t, c2, k, pl), (t, _kv_step(c, c2))
-
-                (_, caches, key, planes), (ys, kv) = jax.lax.scan(
-                    body, (toks, caches, key, planes), None, length=steps - 1
-                )
-                gen = jnp.concatenate(
-                    [toks, jnp.moveaxis(ys[..., 0], 0, 1)], axis=1
-                )
-                return gen, caches, key, planes, kv
-
-            return self._loop_sentinel.wrap(loop, donate_argnums=(2, 3, 5))
-
-        def loop(params, logits, caches, key, temperature):
+        # the loop planes are one more donated carry, folded after every
+        # sampling event (first greedy token included); the paged-KV counts
+        # leave the scan per step (``_kv_step``)
+        def loop(params, logits, caches, key, temperature, planes):
             toks = sample(logits[:, -1:], key, temperature=0.0,
                           vocab=cfg.vocab)
+            planes = loop_update(planes, toks, vocab=cfg.vocab)
 
             def body(carry, _):
-                t, c, k = carry
+                t, c, k, pl = carry
                 k, sub = jax.random.split(k)
-                lg, c = M.decode_step(params, cfg, t, c, kv_mode=kv_mode,
-                                      fused=fused, mesh=mesh)
+                lg, c2 = M.decode_step(params, cfg, t, c, kv_mode=kv_mode,
+                                       fused=fused, mesh=mesh)
                 t = sample_traced(lg, sub, temperature, vocab=cfg.vocab)
-                return (t, c, k), t
+                pl = loop_update(pl, t, vocab=cfg.vocab)
+                return (t, c2, k, pl), (t, _kv_step(c, c2))
 
-            (_, caches, key), ys = jax.lax.scan(
-                body, (toks, caches, key), None, length=steps - 1
+            (_, caches, key, planes), (ys, kv) = jax.lax.scan(
+                body, (toks, caches, key, planes), None, length=steps - 1
             )
             # ys: (steps-1, B, 1) -> (B, steps-1); prepend the first token
-            gen = jnp.concatenate([toks, jnp.moveaxis(ys[..., 0], 0, 1)],
-                                  axis=1)
-            return gen, caches, key
+            gen = jnp.concatenate(
+                [toks, jnp.moveaxis(ys[..., 0], 0, 1)], axis=1
+            )
+            return gen, caches, key, planes, kv
 
-        return self._loop_sentinel.wrap(loop, donate_argnums=(2, 3))
+        return self._loop_sentinel.wrap(loop, donate_argnums=(2, 3, 5))
 
     # -- ghost-hit feed (true-adaptive paged KV, DESIGN.md §8) --------------
     @property
@@ -405,8 +366,7 @@ class ServeEngine:
         else:
             self.registry.mount("tenant", self._tenant_provider)
         self.registry.mount("kv", self._kv_provider)
-        self.registry.mount("moe", lambda: (
-            self._moe_counts if self.metrics and self._moe_counts else {}))
+        self.registry.mount("moe", lambda: self._moe_counts or {})
         self.registry.mount(
             "expert",
             lambda: (
@@ -423,10 +383,7 @@ class ServeEngine:
             self.registry.mount("profiler", self._capture.metrics)
 
     def _serve_provider(self) -> dict:
-        out: dict = dict(self.stats)
-        if self._planes is not None:
-            out["loop"] = dict(self._planes)
-        return out
+        return {**self.stats, "loop": dict(self._planes)}
 
     def _tenant_provider(self) -> dict:
         mgr = self.tenant_cache.manager
@@ -469,9 +426,8 @@ class ServeEngine:
         if self.kv_mode != "paged":
             return {}
         out: dict = {"pool": {"policy": self.cfg.kv_policy,
-                              "pages": self.cfg.bounded_kv_pages}}
-        if self.metrics:
-            out["pool"].update(self._kv_counts)
+                              "pages": self.cfg.bounded_kv_pages,
+                              **self._kv_counts}}
         for t, states in self._kv_sessions.items():
             tel = [paged_kv.pool_telemetry(s) for s in states]
             out[t] = {
@@ -530,23 +486,10 @@ class ServeEngine:
 
     def _admit(self, requests: List[Request]) -> List[str]:
         """Admission decisions for ``requests`` in order, with the
-        decay-on-shed probation credit applied.  ``jit_loop`` engines run
-        one jitted device scan (``decide_batch`` — decides AND decays);
-        host engines run the per-request host loop.  Both paths are
-        bit-identical on identical streams (the parity property test)."""
-        mgr = self.tenant_cache.manager
-        if self.jit_loop:
-            return self.admission.decide_batch(
-                mgr, [r.tenant_id for r in requests])
-        decisions = []
-        for r in requests:
-            d = self.admission.decide(mgr, r.tenant_id)
-            if d == SHED:
-                # refused work is probation time: decay the EWMA so a
-                # shed tenant can re-enter once its burst has passed
-                mgr.decay_pressure(r.tenant_id)
-            decisions.append(d)
-        return decisions
+        decay-on-shed probation credit applied: one jitted device scan
+        (``AdmissionController.decide_batch`` — decides AND decays)."""
+        return self.admission.decide_batch(
+            self.tenant_cache.manager, [r.tenant_id for r in requests])
 
     def generate(self, requests: List[Request]) -> Dict[int, Result]:
         """Length-bucketed batched generation.  Multi-tenant engines run an
@@ -691,8 +634,7 @@ class ServeEngine:
             cached = self._lookup_prefix(reqs[0])
         if cached is not None:
             logits, caches = cached
-            if self.jit_loop:
-                caches = self._snapshot(caches)  # loop will consume them
+            caches = self._snapshot(caches)  # the loop will consume them
             was_cached = True
         else:
             logits, caches = self._batch_prefill(prompts)
@@ -702,11 +644,7 @@ class ServeEngine:
                     # prefix miss -> this prefill re-references page
                     # positions the tenant's previous pool may have evicted
                     caches = self._kv_reseed(caches, reqs[0].tenant_id, plen)
-                payload = (
-                    (logits, self._snapshot(caches)) if self.jit_loop
-                    else (logits, caches)
-                )
-                self._insert_prefix(reqs[0], payload)
+                self._insert_prefix(reqs[0], (logits, self._snapshot(caches)))
 
         prefill_counts = None
         if self._moe_counts is not None:
@@ -715,50 +653,20 @@ class ServeEngine:
             prefill_counts = None if was_cached else caches["moe"]
             caches = {**caches, "moe": jnp.zeros_like(caches["moe"])}
         caches = self._shard_caches(caches, len(reqs))
-        if self.jit_loop:
-            loop = self._get_loop(max_new)
-            # the span contains the host pull serving itself performs
-            # (np.asarray of the tokens) — async dispatch means a span
-            # around the bare call would time only enqueue; sync mode
-            # additionally blocks on the caches (obs.spans docstring)
-            with self.spans.span("decode") as sp:
-                if self.metrics:
-                    gen_dev, caches, self.key, self._planes, kv = loop(
-                        self.params, logits, caches, self.key,
-                        jnp.float32(reqs[0].temperature), self._planes)
-                else:
-                    gen_dev, caches, self.key = loop(
-                        self.params, logits, caches, self.key,
-                        jnp.float32(reqs[0].temperature))
-                sp.ready(caches)
-                gen = np.asarray(gen_dev)
-            if self.metrics:
-                self._add_kv_counts(kv)
-            self.stats["decode_steps"] += max_new - 1
-        else:
-            with self.spans.span("decode"):
-                toks = sample(logits[:, -1:], self.key, temperature=0.0,
-                              vocab=self.cfg.vocab)
-                if self.metrics:
-                    self._planes = self._fold(self._planes, toks)
-                generated, kv = [toks], []
-                for step in range(max_new - 1):
-                    self.key, sub = jax.random.split(self.key)
-                    prev = caches
-                    logits, caches = self._decode(self.params, toks, caches)
-                    toks = sample(logits, sub,
-                                  temperature=reqs[0].temperature,
-                                  vocab=self.cfg.vocab)
-                    if self.metrics:
-                        self._planes = self._fold(self._planes, toks)
-                        kv.append(self._kv_step(prev, caches))
-                    generated.append(toks)
-                    self.stats["decode_steps"] += 1
-                gen = np.concatenate(
-                    [np.asarray(t) for t in generated], axis=1)
-            if self.metrics and kv:
-                self._add_kv_counts(jnp.stack(kv))
-        if self.metrics and prefill_counts is not None:
+        loop = self._get_loop(max_new)
+        # the span contains the host pull serving itself performs
+        # (np.asarray of the tokens) — async dispatch means a span
+        # around the bare call would time only enqueue; sync mode
+        # additionally blocks on the caches (obs.spans docstring)
+        with self.spans.span("decode") as sp:
+            gen_dev, caches, self.key, self._planes, kv = loop(
+                self.params, logits, caches, self.key,
+                jnp.float32(reqs[0].temperature), self._planes)
+            sp.ready(caches)
+            gen = np.asarray(gen_dev)
+        self._add_kv_counts(kv)
+        self.stats["decode_steps"] += max_new - 1
+        if prefill_counts is not None:
             self._add_moe_counts("prefill", prefill_counts)
         if single and self._ghost_feed_on:
             self._kv_persist(caches, reqs[0].tenant_id)

@@ -586,7 +586,8 @@ class AdmissionController:
         sequential scan of ``admission_decide`` + decay-on-shed over the
         batch, bit-identical to calling ``decide`` per request and
         ``manager.decay_pressure`` on each shed (later requests see the
-        pressure decayed by earlier sheds, exactly like the host loop).
+        pressure decayed by earlier sheds, exactly like that per-request
+        loop, which tests keep as the reference).
 
         Bit-identity holds because both paths read the same float32
         pressure plane: the host mirror is a pulled copy of

@@ -10,16 +10,16 @@ from __future__ import annotations
 import numpy as np
 
 from repro.core.simulator import simulate
-from benchmarks.trace_suite import suite
+from studies.trace_suite import suite
 
 GRID = [(1.0, 1.0), (0.5, 1.0), (2.0, 1.0), (1.0, 0.5), (1.0, 2.0),
         (0.5, 2.0), (2.0, 0.5)]
 
 
-def run(out_lines=None):
+def run():
     """Sweep AWRP's (alpha, beta) weighting grid over the trace suite and
-    print mean hit %% per configuration (CSV rows appended to
-    ``out_lines``) — the paper-§5 sensitivity direction."""
+    print mean hit %% per configuration — the paper-§5 sensitivity
+    direction."""
     print("== AWRP(alpha, beta) ablation: mean hit % over 4 cache sizes ==")
     header = f"{'trace':>14} | " + " | ".join(f"a{a:g}/b{b:g}" for a, b in GRID)
     print(header)
@@ -47,10 +47,6 @@ def run(out_lines=None):
     print(f"paper eq.(1) mean: {base:.2f}%  |  best "
           f"(a={overall[0]:g}, b={overall[1]:g}): {best_v:.2f}% "
           f"({best_v - base:+.2f}pp)")
-    if out_lines is not None:
-        out_lines.append(f"awrp_ablation_eq1,0,{base:.2f}%")
-        out_lines.append(
-            f"awrp_ablation_best_a{overall[0]:g}_b{overall[1]:g},0,{best_v:.2f}%")
 
 
 if __name__ == "__main__":

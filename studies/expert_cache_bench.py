@@ -29,10 +29,9 @@ def _trace(E, alpha, phases, n=20_000, seed=0):
     return np.concatenate(parts)
 
 
-def run(out_lines=None):
+def run():
     """Replay phase-drifting Zipf expert-routing traces through each cache
-    policy and report hit ratio plus host-to-device GB moved (CSV rows
-    appended to ``out_lines``)."""
+    policy and report hit ratio plus host-to-device GB moved."""
     print("== expert cache (policy -> hit ratio | GB transferred) ==")
     pols = ["awrp", "lru", "fifo", "lfu", "car", "arc"]
     for name, E, cap, mb, alpha, phases in CASES:
@@ -42,10 +41,6 @@ def run(out_lines=None):
             f"{p}:{100*res[p]['hit_ratio']:.1f}%/"
             f"{res[p]['transfer_bytes']/2**30:.0f}GB" for p in pols)
         print(f"  {name:>24}: {row}")
-        if out_lines is not None:
-            for p in pols:
-                out_lines.append(
-                    f"expert_{name}_{p},0,{100*res[p]['hit_ratio']:.2f}%")
 
     # runtime paths: per-layer host oracles vs the batched device core
     # (identical accounting — parity-tested; here we time the two paths)
@@ -68,10 +63,6 @@ def run(out_lines=None):
     print(f"  runtime route_step ({n_layers} layers x top-{k}): "
           f"host {rows[False][0]:.0f}us | device {rows[True][0]:.0f}us "
           f"per step (identical hit ratio {100*rows[False][1]:.1f}%)")
-    if out_lines is not None:
-        out_lines.append(f"expert_runtime_host,{rows[False][0]:.0f},us_per_step")
-        out_lines.append(f"expert_runtime_device,{rows[True][0]:.0f},us_per_step")
-    return None
 
 
 if __name__ == "__main__":

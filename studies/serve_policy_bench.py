@@ -111,11 +111,10 @@ def _drive(policy: str, steps: int, seed: int):
     return retained / steps, dt / steps * 1e6
 
 
-def run(out_lines=None, smoke: bool = False):
+def run():
     """Ablate paged-KV eviction policies (classic vs true-adaptive) on
-    identical decode traces, scoring oracle attention mass retained;
-    ``smoke`` shrinks the decode; CSV rows appended to ``out_lines``."""
-    steps = 384 if smoke else 1536
+    identical decode traces, scoring oracle attention mass retained."""
+    steps = 1536
     print("== paged-KV serving ablation: oracle attention mass retained ==")
     print(f"   pool {PAGES} pages x {PAGE_SIZE} tokens, {steps}-step decode, "
           f"hot-set phase changes")
@@ -126,10 +125,6 @@ def run(out_lines=None, smoke: bool = False):
         results[policy] = kept
         label = ("true-adaptive" if policy in ADAPTIVE else "classic")
         print(f"{policy:>14} | {100 * kept:12.2f}% | {us:8.1f}  [{label}]")
-        if out_lines is not None:
-            out_lines.append(
-                f"serve_policy_{policy},{us:.1f},{100 * kept:.2f}%_retained"
-            )
     assert all(0.0 < v <= 1.0 for v in results.values())
     # every resident-set policy must beat blind FIFO rotation on this
     # locality+hot-set mix for the bench to be meaningfully discriminative

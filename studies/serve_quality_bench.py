@@ -28,10 +28,9 @@ def _kl(p_logits, q_logits, vocab):
     return float(jnp.sum(jnp.exp(p) * (p - q), axis=-1).mean())
 
 
-def run(out_lines=None, steps: int = 48, pages: int = 4, page_size: int = 8):
+def run(steps: int = 48, pages: int = 4, page_size: int = 8):
     """Serve the same decode under full KV vs each bounded-KV policy and
-    report the logits KL vs the full-cache reference (CSV rows appended
-    to ``out_lines``)."""
+    report the logits KL vs the full-cache reference."""
     base = load_smoke_config("gemma3_27b")
     base = dataclasses.replace(base, dtype="float32", param_dtype="float32",
                                bounded_kv_pages=pages, page_size=page_size)
@@ -69,9 +68,6 @@ def run(out_lines=None, steps: int = 48, pages: int = 4, page_size: int = 8):
         results[pol] = (float(np.mean(kls)), float(np.mean(agree)))
         print(f"  {pol:>5}: KL(full||bounded)={results[pol][0]:.4f} "
               f"greedy-agreement={results[pol][1]*100:.1f}%")
-        if out_lines is not None:
-            out_lines.append(f"serve_kl_{pol},0,{results[pol][0]:.4f}")
-            out_lines.append(f"serve_agree_{pol},0,{results[pol][1]*100:.1f}%")
     return results
 
 

@@ -24,30 +24,20 @@ PAPER_TABLE1 = {
 CAPS = [30, 60, 90, 120, 150, 180, 210, 240]  # paper text says 8 sizes
 
 
-def run(out_lines=None):
+def run():
     """Reproduce the paper's Table 1 hit-ratio grid on the calibrated
-    stand-in trace and check AWRP's gain ordering (CSV rows appended to
-    ``out_lines``)."""
+    stand-in trace and print AWRP's mean gain over each baseline."""
     tr = paper_trace()
     res = sweep(["lru", "fifo", "car", "awrp"], tr, CAPS)
     print("== Table 1 reproduction (stand-in trace; paper digits in brackets) ==")
     print(hit_ratio_table(res, CAPS))
-    gains = {}
     for other in ("lru", "fifo", "car"):
         ours = np.mean([res["awrp"][c] - res[other][c] for c in CAPS]) * 100
         caps7 = [c for c in CAPS if c in PAPER_TABLE1["awrp"]]
         paper = np.mean([PAPER_TABLE1["awrp"][c] - PAPER_TABLE1[other][c]
                          for c in caps7])
-        gains[other] = (ours, paper)
         print(f"AWRP mean gain vs {other.upper():4s}: ours {ours:+.2f}pp | "
               f"paper {paper:+.2f}pp")
-    if out_lines is not None:
-        for other, (ours, paper) in gains.items():
-            out_lines.append(
-                f"table1_gain_vs_{other},0,{ours:+.3f}pp(paper {paper:+.2f}pp)")
-        for c in CAPS:
-            out_lines.append(
-                f"table1_awrp_hit_cap{c},0,{100*res['awrp'][c]:.2f}%")
     return res
 
 

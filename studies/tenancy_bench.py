@@ -23,8 +23,6 @@ three mounts are apples-to-apples by construction.
 
 from __future__ import annotations
 
-import json
-import os
 import time
 
 import numpy as np
@@ -90,12 +88,10 @@ def _rebalanced(policy, quotas, tenant_rows, keys, chunks=8):
     return _per_tenant_hits(tenant_rows, hits), mgr.quotas, moves
 
 
-def run(out_lines=None, smoke: bool = False, sweep_json=None):
+def run():
     """Ablate shared cache vs quota rows vs AWRP-ranked rebalancing on
-    the identical interleaved multi-tenant trace; merges the ``tenancy``
-    record into ``sweep_json``.  ``smoke`` shrinks the trace; CSV rows
-    appended to ``out_lines``."""
-    n = 1_500 if smoke else 6_000
+    the identical interleaved multi-tenant trace."""
+    n = 6_000
     policy = "awrp"
     quotas = (16, 16, 16)
     tenant_rows, keys = _trace(n)
@@ -131,44 +127,6 @@ def run(out_lines=None, smoke: bool = False, sweep_json=None):
           + ", ".join(f"{t}: hr={tel[t]['hit_ratio']:.3f} "
                       f"ev={tel[t]['evictions']} p={tel[t]['pressure']:.2f}"
                       for t in TENANTS))
-
-    if out_lines is not None:
-        out_lines.append(f"tenancy_quota_rows,{us:.2f},"
-                         f"{weighted(rows):.4f}_weighted_hit_ratio")
-        out_lines.append(f"tenancy_shared,0,{weighted(shared):.4f}"
-                         f"_weighted_hit_ratio")
-        out_lines.append(f"tenancy_rebalanced,0,{weighted(rebal):.4f}"
-                         f"_weighted_hit_ratio")
-    if sweep_json is not None:
-        record = {
-            "policy": policy,
-            "n_accesses": n,
-            "quotas": list(quotas),
-            "per_tenant_hit_ratio": {
-                mount: dict(zip(TENANTS, [round(x, 4) for x in ratios(s)]))
-                for mount, s in (("shared", shared), ("quota_rows", rows),
-                                 ("rebalanced", rebal))
-            },
-            "weighted_hit_ratio": {
-                "shared": round(weighted(shared), 4),
-                "quota_rows": round(weighted(rows), 4),
-                "rebalanced": round(weighted(rebal), 4),
-            },
-            "rebalance_moves": moves,
-            "us_per_access_quota_rows": round(us, 2),
-        }
-        # merge into the sweep perf artifact (policy_overhead writes the
-        # base record; section order in run.py guarantees it runs first
-        # when both sections are selected)
-        base = {}
-        if os.path.exists(sweep_json):
-            with open(sweep_json) as fh:
-                base = json.load(fh)
-        base["tenancy"] = record
-        with open(sweep_json, "w") as fh:
-            json.dump(base, fh, indent=2)
-            fh.write("\n")
-        print(f"(tenancy record merged into {sweep_json})")
 
 
 if __name__ == "__main__":

@@ -36,20 +36,16 @@ def suite():
     }
 
 
-def run(out_lines=None, smoke: bool = False):
+def run():
     """Sweep every policy over the generalization trace suite at 4 cache
-    sizes and print mean hit ratios (``smoke`` trims the policy list;
-    CSV rows appended to ``out_lines``)."""
+    sizes and print mean hit ratios."""
     print("== trace suite: mean hit ratio over 4 cache sizes (10/25/50/75% of "
           "working set) ==")
     header = f"{'trace':>14} | " + " | ".join(f"{p:>6}" for p in POLICIES)
     print(header)
     print("-" * len(header))
     agg = {p: [] for p in POLICIES}
-    traces = suite()
-    if smoke:  # one real-program trace + one locality model
-        traces = {k: traces[k] for k in ("mergesort", "zipf_a0.8")}
-    for name, tr in traces.items():
+    for name, tr in suite().items():
         u = len(np.unique(tr))
         caps = sorted({max(4, int(u * f)) for f in (0.1, 0.25, 0.5, 0.75)})
         res = sweep(POLICIES, tr, caps)
@@ -59,9 +55,6 @@ def run(out_lines=None, smoke: bool = False):
         print(f"{name:>14} | " + " | ".join(f"{100*means[p]:6.2f}" for p in POLICIES))
     print(f"{'MEAN':>14} | " + " | ".join(
         f"{100*np.mean(agg[p]):6.2f}" for p in POLICIES))
-    if out_lines is not None:
-        for p in POLICIES:
-            out_lines.append(f"trace_suite_mean_{p},0,{100*np.mean(agg[p]):.2f}%")
     return agg
 
 

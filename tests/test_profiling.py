@@ -1,5 +1,5 @@
-"""Performance-observability suite (obs.profiling / obs.server /
-tools/bench_history — DESIGN.md §12).
+"""Performance-observability suite (obs.profiling / obs.server —
+DESIGN.md §12).
 
 Pins the PR's acceptance contracts:
 
@@ -16,18 +16,11 @@ Pins the PR's acceptance contracts:
   ``sync`` discipline's ``ready()`` hook;
 * **live export** — the background HTTP ``/metrics`` endpoint serves
   the same snapshot ``telemetry()`` returns, and the periodic JSONL
-  logger appends parseable lines;
-* **bench history** — ``--update`` splits a sweep artifact into
-  per-section baselines and ``--check`` fails on tolerance-exceeding
-  regressions, honors cpu_count-gated timing tolerances, and flags
-  dropped sections/metrics.
+  logger appends parseable lines.
 """
 
 import dataclasses
-import importlib.util
 import json
-import os
-import sys
 import urllib.request
 
 import jax
@@ -42,13 +35,6 @@ from repro.obs.profiling import Sentinel, TraceCapture, count_eqns, instrument
 from repro.obs.server import MetricsServer, SnapshotLogger
 from repro.obs.spans import SpanSet
 from repro.serve.engine import Request, ServeEngine
-
-_BH_PATH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "tools", "bench_history.py")
-_spec = importlib.util.spec_from_file_location("bench_history", _BH_PATH)
-bench_history = importlib.util.module_from_spec(_spec)
-sys.modules["bench_history"] = bench_history  # dataclasses resolves via it
-_spec.loader.exec_module(bench_history)
 
 
 @pytest.fixture(scope="module")
@@ -427,135 +413,3 @@ def test_snapshot_logger_appends_final_line_on_stop(tmp_path):
     assert len(lines) == 1 and lg.lines == 1 and lg.errors == 0
     rec = json.loads(lines[0])
     assert rec["arch"] == "x" and rec["serve/requests"] == 1 and "ts" in rec
-
-
-# ---------------------------------------------------------------------------
-# bench history: update / check / tolerances
-# ---------------------------------------------------------------------------
-
-
-def _sweep_doc():
-    """Synthetic sweep artifact exercising every section's gate shapes."""
-    return {
-        "n_accesses": 1000, "grid_configs": 18,
-        "policies": ["awrp", "lru"], "capacities": [4, 8],
-        "host_loop_s": 2.0, "device_grid_s": 0.2,
-        "grid_accesses_per_s": 90000.0, "speedup_vs_host": 10.0,
-        "parity_with_host_oracles": True,
-        "serve_loop": {
-            "n_requests": 6, "new_tokens": 8,
-            "requests_per_sec": {"jit_loop": 2.0, "host_loop": 1.0},
-            "speedup_jit_vs_host": 2.0,
-            "admission_us_per_decision": {"host": 50.0, "device_batch": 9.0},
-            "admission_bit_identical": True,
-        },
-        "obs_overhead": {
-            "cpu_count": os.cpu_count(),
-            "requests_per_sec": {"metrics_on": 2.0, "metrics_off": 2.05},
-            "overhead_frac": 0.02, "gate_max_overhead": 0.05,
-            "snapshot_us": 900, "trace_drain_us": 300, "opt_regret_us": 4000,
-        },
-        "policy_attn": {
-            "B": 2, "pages": 4, "page_size": 8, "steps": 6, "devices": 8,
-            "policies": {
-                "awrp": {"fused_eqns": 10, "unfused_eqns": 40,
-                         "dispatch_reduction": 4.0, "bit_identical": True,
-                         "mesh_bit_identical": True,
-                         "fused_us_per_step_interpret": 100.0,
-                         "unfused_us_per_step_interpret": 50.0},
-            },
-        },
-    }
-
-
-def test_bench_history_update_then_check_passes(tmp_path):
-    sweep = tmp_path / "BENCH_sweep.json"
-    sweep.write_text(json.dumps(_sweep_doc()))
-    bdir = str(tmp_path / "baselines")
-    written = bench_history.update(str(sweep), bdir)
-    names = {os.path.basename(p) for p in written}
-    assert names == {"BENCH_sweep.json", "BENCH_serve_loop.json",
-                     "BENCH_obs_overhead.json", "BENCH_policy_attn.json"}
-    base = json.loads((tmp_path / "baselines" / "BENCH_sweep.json")
-                      .read_text())
-    assert base["section"] == "sweep"
-    assert base["meta"]["cpu_count"] == os.cpu_count()
-    assert "serve_loop" not in base["record"]  # sections split out
-    diff = bench_history.check(str(sweep), bdir)
-    assert diff["failures"] == 0 and diff["checked"] > 10
-
-
-def test_bench_history_check_fails_on_regression(tmp_path):
-    sweep = tmp_path / "BENCH_sweep.json"
-    doc = _sweep_doc()
-    sweep.write_text(json.dumps(doc))
-    bench_history.update(str(sweep), str(tmp_path / "b"))
-    # regress a timing metric beyond tolerance AND flip a parity bool
-    doc["speedup_vs_host"] = 10.0 * (1 - 0.30) - 1  # below the 30% floor
-    doc["policy_attn"]["policies"]["awrp"]["fused_eqns"] = 99  # eqn bloat
-    sweep.write_text(json.dumps(doc))
-    diff = bench_history.check(str(sweep), str(tmp_path / "b"))
-    failed = {r["path"] for s in diff["sections"].values()
-              for r in s["gates"] if r["status"] == "FAIL"}
-    assert "policies.awrp.fused_eqns" in failed
-    assert diff["failures"] >= 2
-    assert "speedup_vs_host" in failed
-
-
-def test_bench_history_timing_gates_skip_on_cpu_mismatch(tmp_path):
-    sweep = tmp_path / "BENCH_sweep.json"
-    doc = _sweep_doc()
-    sweep.write_text(json.dumps(doc))
-    bdir = str(tmp_path / "b")
-    bench_history.update(str(sweep), bdir)
-    # forge a baseline machine with a different core count
-    for fn in os.listdir(bdir):
-        p = os.path.join(bdir, fn)
-        d = json.loads(open(p).read())
-        d["meta"]["cpu_count"] = (os.cpu_count() or 1) + 7
-        with open(p, "w") as fh:
-            json.dump(d, fh)
-    # timing regression that WOULD fail on a matched machine...
-    doc["speedup_vs_host"] = 0.1
-    sweep.write_text(json.dumps(doc))
-    diff = bench_history.check(str(sweep), bdir)
-    assert diff["failures"] == 0  # ...is honestly skipped
-    assert diff["skipped"] > 0
-    # but exact-match gates still bind across machines
-    doc["parity_with_host_oracles"] = False
-    sweep.write_text(json.dumps(doc))
-    diff = bench_history.check(str(sweep), bdir)
-    assert diff["failures"] == 1
-
-
-def test_bench_history_check_fails_on_dropped_section(tmp_path):
-    sweep = tmp_path / "BENCH_sweep.json"
-    doc = _sweep_doc()
-    sweep.write_text(json.dumps(doc))
-    bdir = str(tmp_path / "b")
-    bench_history.update(str(sweep), bdir)
-    del doc["policy_attn"]  # the bench stopped running
-    sweep.write_text(json.dumps(doc))
-    diff = bench_history.check(str(sweep), bdir)
-    assert diff["failures"] >= 1
-    rows = diff["sections"]["policy_attn"]["gates"]
-    assert rows[0]["status"] == "FAIL" and "missing" in rows[0]["note"]
-
-
-def test_bench_history_cli_exit_codes(tmp_path):
-    sweep = tmp_path / "s.json"
-    sweep.write_text(json.dumps(_sweep_doc()))
-    bdir = str(tmp_path / "b")
-    assert bench_history.main(["--update", "--sweep", str(sweep),
-                               "--baseline-dir", bdir]) == 0
-    diff_out = tmp_path / "diff.json"
-    assert bench_history.main(["--check", "--sweep", str(sweep),
-                               "--baseline-dir", bdir,
-                               "--diff-out", str(diff_out)]) == 0
-    assert json.loads(diff_out.read_text())["failures"] == 0
-    bad = _sweep_doc()
-    bad["obs_overhead"]["overhead_frac"] = 0.5  # absolute ceiling gate
-    sweep.write_text(json.dumps(bad))
-    assert bench_history.main(["--check", "--sweep", str(sweep),
-                               "--baseline-dir", bdir]) == 1
-    assert bench_history.main(["--show", "--baseline-dir", bdir]) == 0

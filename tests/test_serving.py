@@ -276,8 +276,7 @@ def test_bounded_kv_true_adaptive_engine_runs_past_pool_capacity(kv_policy):
     assert eng.telemetry()["kv/pool/policy"] == kv_policy
 
 
-@pytest.mark.parametrize("jit_loop", [True, False])
-def test_kv_pool_counters_do_not_wrap_past_int32(jit_loop):
+def test_kv_pool_counters_do_not_wrap_past_int32():
     """``kv/pool/hits`` and ``kv/pool/evictions`` keep counting past 2**31
     (a long-lived engine): each generate's per-step counts are added on the
     host, so a total near the int32 limit grows by exactly what a fresh
@@ -288,8 +287,7 @@ def test_kv_pool_counters_do_not_wrap_past_int32(jit_loop):
     params = M.init_params(cfg, jax.random.PRNGKey(1))
 
     def counts(start):
-        eng = ServeEngine(cfg, params, max_len=128, kv_mode="paged",
-                          jit_loop=jit_loop)
+        eng = ServeEngine(cfg, params, max_len=128, kv_mode="paged")
         eng._kv_counts.update(hits=start, evictions=start)
         eng.generate([Request(0, list(range(1, 17)), max_new_tokens=30)])
         tel = eng.telemetry()
@@ -470,28 +468,12 @@ def test_deferred_then_completed_matches_unpressured_telemetry(engine):
     assert sd == sp
 
 
-def test_jit_loop_matches_host_loop_greedy(engine):
-    """The donated-buffer scan loop and the host per-step loop agree on
-    greedy decode (argmax is stable across the two compilation contexts),
-    and the jit loop counts the same decode steps."""
-    jit_eng = ServeEngine(engine.cfg, engine.params, max_len=96,
-                          jit_loop=True)
-    host_eng = ServeEngine(engine.cfg, engine.params, max_len=96,
-                           jit_loop=False)
-    prompt = list(range(7, 23))
-    rj = jit_eng.generate([Request(0, list(prompt), max_new_tokens=6)])
-    rh = host_eng.generate([Request(0, list(prompt), max_new_tokens=6)])
-    assert rj[0].tokens == rh[0].tokens
-    assert len(rj[0].tokens) == 6
-    assert jit_eng.stats["decode_steps"] == host_eng.stats["decode_steps"]
-
-
 def test_jit_loop_prefix_payload_survives_donation(engine):
     """Donation regression: stored prefix payloads must be snapshots —
     aliasing them with the loop's donated buffers would invalidate the
     entry on first reuse (jax deletes donated arrays).  Three hits on the
     same entry prove the payload outlives repeated donated loops."""
-    eng = ServeEngine(engine.cfg, engine.params, max_len=96, jit_loop=True)
+    eng = ServeEngine(engine.cfg, engine.params, max_len=96)
     prompt = list(range(60, 76))
     first = eng.generate([Request(0, list(prompt), max_new_tokens=4)])
     outs = [eng.generate([Request(i, list(prompt), max_new_tokens=4)])
@@ -540,5 +522,5 @@ def test_serve_cli_main_runs_in_process(monkeypatch, capsys):
                 "--new-tokens", "3", "--prompt-len", "64", "--kv-mode",
                 "paged", "--fused"])
     out = capsys.readouterr().out
-    assert "kv_mode=paged policy=awrp loop=jit fused=True" in out
+    assert "kv_mode=paged policy=awrp fused=True" in out
     assert "2 requests, 6 tokens" in out

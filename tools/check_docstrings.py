@@ -1,10 +1,9 @@
 """Docstring lint for the public serving surface (CI doc-checks job).
 
 Walks the packages listed in ``TARGETS`` — the serve/core/cache library
-surface plus the benchmark entry points (every ``benchmarks/*.py`` is a
-public artifact producer whose ``run``/helpers CI invokes) and the
-``tools/`` scripts (CI gates themselves: bench_history, the smoke
-runners, this linter) — and fails
+surface plus the policy studies (every ``studies/*.py`` is a runnable
+study whose ``run``/helpers are public) and the ``tools/`` scripts (CI
+gates themselves: the smoke runners, this linter) — and fails
 (exit 1, one line per violation) when a public module, class, function
 or method has no docstring.  "Public" means the name has no leading underscore and the
 object is defined at module or class level — nested helpers and
@@ -25,7 +24,7 @@ import sys
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 TARGETS = ("src/repro/serve", "src/repro/core", "src/repro/cache",
-           "src/repro/kernels", "src/repro/obs", "benchmarks", "tools")
+           "src/repro/kernels", "src/repro/obs", "studies", "tools")
 
 
 def _missing(tree: ast.Module, path: pathlib.Path):

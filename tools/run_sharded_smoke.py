@@ -1,18 +1,16 @@
-"""Multi-device smoke driver: run the sharded parity suite + sweep bench.
+"""Multi-device smoke driver: run the sharded parity suite.
 
 Forces ``N`` XLA host devices (default 8) via
-``--xla_force_host_platform_device_count`` and then runs, in child
-processes so the flag is guaranteed to precede the first jax import:
+``--xla_force_host_platform_device_count`` and then runs, in a child
+process so the flag is guaranteed to precede the first jax import,
+``tests/test_sharding.py``, the fast fused-kernel suite
+``tests/test_policy_attn.py`` and ``tests/test_obs.py`` — the
+bit-identity property suites at the forced device count (the mesh parity
+cases that skip in plain tier-1 actually run here, including the fused
+``shard_map`` path).
 
-1. ``tests/test_sharding.py`` + the fast fused-kernel suite
-   ``tests/test_policy_attn.py`` — the bit-identity property suites at
-   the forced device count (the mesh parity cases that skip in plain
-   tier-1 actually run here, including the fused ``shard_map`` path);
-2. ``benchmarks/run.py --sections sharded_sweep --smoke`` — the sweep
-   engine's parity gate + scaling record.
-
-Exit status is non-zero if either step fails — this is the command the CI
-``multi-device`` job runs, and the one to reproduce it locally::
+Exit status is the suite's — this is the command the CI ``multi-device``
+job runs, and the one to reproduce it locally::
 
     python tools/run_sharded_smoke.py [--devices 8]
 """
@@ -28,8 +26,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def main(argv=None) -> int:
-    """Run both smoke steps at the forced device count; return the first
-    non-zero child exit status (0 if both pass)."""
+    """Run the sharded parity suite at the forced device count; return
+    its exit status."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--devices", type=int, default=8, metavar="N",
                     help="XLA host device count to force (default 8)")
@@ -44,25 +42,16 @@ def main(argv=None) -> int:
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(REPO, "src"), env.get("PYTHONPATH")) if p)
 
-    steps = [
-        ("sharded parity suite",
-         [sys.executable, "-m", "pytest", "-x", "-q",
-          os.path.join(REPO, "tests", "test_sharding.py"),
-          "-m", "not slow",
-          os.path.join(REPO, "tests", "test_policy_attn.py"),
-          os.path.join(REPO, "tests", "test_obs.py")]),
-        ("sharded sweep bench (parity gate + scaling record)",
-         [sys.executable, os.path.join(REPO, "benchmarks", "run.py"),
-          "--sections", "sharded_sweep", "--smoke"]),
-    ]
-    for name, cmd in steps:
-        print(f"\n== {name} ({args.devices} devices) ==", flush=True)
-        rc = subprocess.call(cmd, env=env, cwd=REPO)
-        if rc != 0:
-            print(f"FAILED: {name} (exit {rc})")
-            return rc
-    print(f"\nmulti-device smoke OK at {args.devices} devices")
-    return 0
+    cmd = [sys.executable, "-m", "pytest", "-x", "-q",
+           os.path.join(REPO, "tests", "test_sharding.py"),
+           "-m", "not slow",
+           os.path.join(REPO, "tests", "test_policy_attn.py"),
+           os.path.join(REPO, "tests", "test_obs.py")]
+    print(f"== sharded parity suite ({args.devices} devices) ==", flush=True)
+    rc = subprocess.call(cmd, env=env, cwd=REPO)
+    status = "OK" if rc == 0 else f"FAILED (exit {rc})"
+    print(f"\nmulti-device smoke {status} at {args.devices} devices")
+    return rc
 
 
 if __name__ == "__main__":
